@@ -139,6 +139,9 @@ def main() -> None:
                     help="smoke-mode JSON output path")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     if args.smoke:
         _smoke(args.out)

@@ -10,7 +10,9 @@ row the paper's massive datasets need), and ``hybrid`` on 2-D
 are recorded per (plan, mesh_shape, devices) row into ``BENCH_scaling.json``.
 
 Each row runs in a subprocess because ``--xla_force_host_platform_device_count``
-must be set before jax initializes.  On a CPU host the forced devices share
+must be set before jax initializes.  The children run with
+``JAX_PLATFORMS=cpu`` (their rows say ``platform: "cpu"``) even on a TPU
+host, where the chip belongs to the parent.  The forced devices share
 the same cores, so this measures the *overhead* of each mesh decomposition
 (shard_map fan-out, per-shard index builds, merge tree, psum, gather) rather
 than real speedup — the point is that the decompositions are load-bearing
@@ -64,6 +66,7 @@ def _child(args) -> None:
         "plan": args.plan,
         "mesh_shape": mesh_shape if isinstance(mesh_shape, int) or mesh_shape
         is None else list(mesh_shape),
+        "platform": jax.devices()[0].platform,
         "devices": int(jax.device_count()),
         "objects": args.objects,
         "k": args.k,
@@ -87,6 +90,8 @@ def run(
     out: str | None = "BENCH_scaling.json",
 ):
     """Sweep plan x mesh shape at fixed total Q; returns the row list."""
+    from repro.launch.mesh import forced_cpu_env
+
     here = os.path.dirname(os.path.abspath(__file__))
     src = os.path.join(here, "..", "src")
     rows = []
@@ -97,12 +102,8 @@ def run(
         + [("hybrid", f"{q}x{o}", q * o) for q, o in hybrid_shapes]
     )
     for plan, mesh, devices in sweep:
-        env = dict(os.environ)
+        env = forced_cpu_env(devices)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={devices}"
-        ).strip()
         cmd = [
             sys.executable, os.path.abspath(__file__), "--child",
             "--plan", plan, "--mesh", mesh,

@@ -18,7 +18,9 @@ EMA feedback loop is live).  Per row we record:
   lockstep ``single``-plan session (the §12/§13 contract, asserted).
 
 Each row runs in a subprocess because
-``--xla_force_host_platform_device_count`` must be set before jax init.
+``--xla_force_host_platform_device_count`` must be set before jax init; the
+children run with ``JAX_PLATFORMS=cpu`` (rows say ``platform: "cpu"``), so
+on a TPU host the chip stays the parent's.
 
   PYTHONPATH=src python benchmarks/s7_skew.py [--objects N] [--ticks T]
 """
@@ -97,6 +99,7 @@ def _child(args) -> None:
         "plan": args.plan,
         "mesh": args.mesh,
         "partitioner": args.partitioner,
+        "platform": jax.devices()[0].platform,
         "devices": int(jax.device_count()),
         "objects": args.objects,
         "ticks": args.ticks,
@@ -127,18 +130,16 @@ def run(
     per-(zipf_a, plan) summary with the equal -> cost_balanced gap ratio —
     the headline number (>1 = cost_balanced is better balanced).
     """
+    from repro.launch.mesh import forced_cpu_env
+
     here = os.path.dirname(os.path.abspath(__file__))
     src = os.path.join(here, "..", "src")
     rows = []
     for zipf_a in exponents:
         for plan, mesh in plans:
             for partitioner in ("equal", "cost_balanced"):
-                env = dict(os.environ)
+                env = forced_cpu_env(devices)
                 env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-                env["XLA_FLAGS"] = (
-                    env.get("XLA_FLAGS", "")
-                    + f" --xla_force_host_platform_device_count={devices}"
-                ).strip()
                 cmd = [
                     sys.executable, os.path.abspath(__file__), "--child",
                     "--plan", plan, "--mesh", mesh,

@@ -33,7 +33,9 @@ the plan sweep.  Per row we record:
   size.
 
 Each row runs in a subprocess because
-``--xla_force_host_platform_device_count`` must be set before jax init.
+``--xla_force_host_platform_device_count`` must be set before jax init; the
+children run with ``JAX_PLATFORMS=cpu`` (rows say ``platform: "cpu"``), so
+on a TPU host the chip stays the parent's.
 
   PYTHONPATH=src python benchmarks/s8_churn.py [--objects N] [--ticks T]
 """
@@ -214,6 +216,7 @@ def _child(args) -> None:
         "mode_used": mode_used,
         "plan": args.plan,
         "mesh": args.mesh,
+        "platform": jax.devices()[0].platform,
         "devices": int(jax.device_count()),
         "objects": n,
         "ticks": args.ticks,
@@ -255,18 +258,16 @@ def run(
     ``check`` (full runs), asserts the §15 acceptance criterion: >= 3x
     stage reduction at every churn level <= 10%.
     """
+    from repro.launch.mesh import forced_cpu_env
+
     here = os.path.dirname(os.path.abspath(__file__))
     src = os.path.join(here, "..", "src")
     rows = []
     for churn in churns:
         for plan, mesh in plans:
             for maintenance in ("rebuild", "incremental"):
-                env = dict(os.environ)
+                env = forced_cpu_env(devices)
                 env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-                env["XLA_FLAGS"] = (
-                    env.get("XLA_FLAGS", "")
-                    + f" --xla_force_host_platform_device_count={devices}"
-                ).strip()
                 cmd = [
                     sys.executable, os.path.abspath(__file__), "--child",
                     "--plan", plan, "--mesh", mesh,

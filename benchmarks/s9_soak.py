@@ -25,7 +25,9 @@ distribution honest.  Per row we record:
   evictions/invalidations) and the epoch count actually consumed.
 
 Each row runs in a subprocess because
-``--xla_force_host_platform_device_count`` must be set before jax init.
+``--xla_force_host_platform_device_count`` must be set before jax init; the
+children run with ``JAX_PLATFORMS=cpu`` (rows say ``platform: "cpu"``), so
+on a TPU host the chip stays the parent's.
 
   PYTHONPATH=src python benchmarks/s9_soak.py [--objects N] [--ticks T]
 """
@@ -144,6 +146,7 @@ def _child(args) -> None:
         "mesh": args.mesh,
         "partitioner": args.partitioner,
         "invalidation": args.invalidation,
+        "platform": jax.devices()[0].platform,
         "devices": int(jax.device_count()),
         "objects": n,
         "tenants": T,
@@ -203,6 +206,8 @@ def run(
     ``check`` (full runs) asserts the §16 acceptance criterion — a NONZERO
     hit rate under the Zipf-overlapping tenant workload on every row.
     """
+    from repro.launch.mesh import forced_cpu_env
+
     here = os.path.dirname(os.path.abspath(__file__))
     src = os.path.join(here, "..", "src")
     if churns is None:
@@ -211,12 +216,8 @@ def run(
     for plan, mesh, partitioner in plans:
         for invalidation in invalidations:
             for c in churns:
-                env = dict(os.environ)
+                env = forced_cpu_env(devices)
                 env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-                env["XLA_FLAGS"] = (
-                    env.get("XLA_FLAGS", "")
-                    + f" --xla_force_host_platform_device_count={devices}"
-                ).strip()
                 cmd = [
                     sys.executable, os.path.abspath(__file__), "--child",
                     "--plan", plan, "--mesh", mesh,

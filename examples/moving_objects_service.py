@@ -43,9 +43,10 @@ to a full rebuild).
 round-robin across N tenants sharing ONE tick program, and each tick's
 object delta arrives via the next tenant in turn (DESIGN.md §16).
 
-``--devices D`` (CPU) forces D host devices via XLA_FLAGS *before* jax
-initializes, so the mesh plans run on a real D-device mesh without
-accelerators.
+``--devices D`` puts the plan's mesh on D devices.  It also forces D host
+devices via XLA_FLAGS *before* jax initializes; that flag affects only the
+CPU platform, so without accelerators the mesh plans run on a real D-device
+CPU mesh, and on a TPU host the mesh takes D of the chips.
 """
 import argparse
 import os
@@ -71,8 +72,9 @@ def _parse_args():
                     choices=["single", "sharded", "object_sharded", "hybrid"],
                     help="execution plan (plan registry)")
     ap.add_argument("--devices", type=int, default=None,
-                    help="devices on the plan's 1-D mesh; on CPU also forces "
-                         "that many host devices (set before jax init)")
+                    help="devices on the plan's 1-D mesh; also forces that "
+                         "many host devices, which affects only the CPU "
+                         "platform (set before jax init)")
     ap.add_argument("--mesh", default=None, metavar="QxO",
                     help="hybrid mesh shape, e.g. 2x4 (query x object "
                          "devices); default: most balanced factorization")
@@ -143,7 +145,7 @@ def main():
         if args.devices is None:
             args.devices = q * o
 
-    # the device count must be pinned before the first jax import
+    # the CPU device count must be pinned before the first jax import
     if args.devices and args.devices > 1:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
@@ -155,7 +157,9 @@ def main():
 
     from repro.api import KnnSession, ServiceSpec
     from repro.data import make_workload
+    from repro.launch.compile_cache import use_compile_cache
 
+    use_compile_cache()
     try:
         spec = ServiceSpec(k=args.k, th_quad=384, l_max=8,
                            window=min(256, args.chunk), chunk=args.chunk,
@@ -251,8 +255,15 @@ def main():
     print(f"\nsteady state: {np.median(steady) * 1e3:.1f} ms/tick = "
           f"{args.objects / np.median(steady):,.0f} queries/s "
           f"[{session.plan.describe()}]")
-    print("(the paper's GPU pipeline is the TPU dry-run target; CPU numbers "
-          "exercise the identical program)")
+    print(f"(ran on {_device_label()})")
+
+
+def _device_label() -> str:
+    """The platform and device kind the run used, as JAX reports them."""
+    import jax
+
+    d = jax.devices()[0]
+    return f"{jax.device_count()} x {d.platform} {d.device_kind!r}"
 
 
 def _serve_tenants(args, spec):
@@ -349,6 +360,7 @@ def _serve_tenants(args, spec):
           f"(lifetime hit rate "
           f"{1 - server.rows_computed / max(served, 1):.2f}) "
           f"[{server.session.plan.describe()}]")
+    print(f"(ran on {_device_label()})")
 
 
 if __name__ == "__main__":

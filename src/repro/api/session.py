@@ -727,14 +727,7 @@ class KnnSession:
             delta_ids_dev,
             delta_old_pos_dev,
             qweight_dev,
-            k=spec.k,
-            window=spec.window,
-            chunk=spec.chunk,
-            max_nav=default_max_nav(spec.l_max),
-            max_iters=spec.max_iters,
-            executor=self.executor,
-            plan=self.plan,
-            maintenance=mode,
+            **self._step_statics(mode),
         )
         # the index is now refreshed from this very buffer: clean until the
         # next position change (the dispatched step reads the buffer as of
@@ -801,6 +794,42 @@ class KnnSession:
         self._tick += 1
         self._pending.append(h)
         return h
+
+    def _step_statics(self, mode: str) -> dict:
+        """The static arguments of :func:`_tick_step` under this spec."""
+        spec = self.spec
+        return dict(
+            k=spec.k, window=spec.window, chunk=spec.chunk,
+            max_nav=default_max_nav(spec.l_max), max_iters=spec.max_iters,
+            executor=self.executor, plan=self.plan, maintenance=mode,
+        )
+
+    def lower_tick(self):
+        """The tick program for the current state, lowered but not run.
+
+        Returns the ``jax.stages.Lowered`` of the full-refresh (``"rebuild"``)
+        step over the live index, object buffer and query registry; its
+        ``compile()`` gives the executable's text (which kernels it runs: a
+        Pallas kernel compiled for the TPU shows as ``tpu_custom_call``) and
+        memory analysis.  Needs a submitted tick (the index is built lazily
+        at the first ``submit()``).
+        """
+        if self._index is None:
+            raise RuntimeError("lower_tick before the first submit: no index")
+        qpos_dev, qid_dev = self._registry.staged()[:2]
+        return _tick_step.lower(
+            self._index,
+            self._positions,
+            qpos_dev,
+            qid_dev,
+            jnp.zeros((qpos_dev.shape[0],), jnp.float32),
+            jnp.float32(np.inf),
+            jnp.float32(self.spec.rebuild_factor),
+            None,
+            None,
+            None,
+            **self._step_statics("rebuild"),
+        )
 
     def process_tick(self, positions, qpos, qid=None):
         """Blocking snapshot convenience: ingest + set_queries + submit + result.

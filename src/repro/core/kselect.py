@@ -15,6 +15,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import bucket_refine_ref
+
 __all__ = ["find_kdist"]
 
 
@@ -56,29 +58,8 @@ def find_kdist(
     kth = jnp.full((q,), k, jnp.int32)
 
     def body(_, state):
-        lo, hi, kth = state
-        width = (hi - lo) / num_bins
-        width = jnp.maximum(width, 1e-30)
-        b = jnp.floor((d - lo[:, None]) / width[:, None])
-        b = jnp.clip(b, 0, num_bins - 1).astype(jnp.int32)
-        in_range = valid & (d >= lo[:, None]) & (d < hi[:, None])
-        onehot = jax.nn.one_hot(b, num_bins, dtype=jnp.int32) * in_range[..., None]
-        hist = onehot.sum(axis=1)  # (Q, num_bins)
-        cum = jnp.cumsum(hist, axis=1)
-        # bucket containing the k-th in-range element
-        sel = (cum >= kth[:, None]).argmax(axis=1)
-        below = jnp.where(sel > 0, jnp.take_along_axis(cum, jnp.maximum(sel - 1, 0)[:, None], 1)[:, 0], 0)
-        new_lo = lo + sel * width
-        new_hi = new_lo + width
-        new_kth = kth - below
-        # float guard: edge rounding can push the k-th element out of [lo, hi);
-        # keep the previous (still-valid) interval in that case.
-        ok = cum[:, -1] >= kth
-        return (
-            jnp.where(ok, new_lo, lo),
-            jnp.where(ok, new_hi, hi),
-            jnp.where(ok, new_kth, kth),
-        )
+        # one histogram level, bucket edges exact (kernels/ref.py)
+        return bucket_refine_ref(d, *state, num_bins)
 
     lo, hi, kth = jax.lax.fori_loop(0, iters, body, (lo, hi, kth))
     r = hi

@@ -34,18 +34,15 @@ __all__ = [
 def _manual_axes_active() -> bool:
     """True while tracing inside a shard_map/pmap manual-axis region.
 
-    jax 0.4.x XLA rejects ``with_sharding_constraint`` under a partially-manual
-    shard_map (``Check failed: sharding.IsManualSubgroup()``), so ``constrain``
-    degrades to identity there — the constraint is an optimization hint, and
-    GSPMD still propagates shardings through the auto axes.  On jax versions
-    without this probe the check returns False and the constraint applies.
+    ``with_sharding_constraint`` rejects a spec that names a manual axis
+    ("can only refer to Auto axes of the mesh"), so ``constrain`` degrades to
+    identity there — the constraint is an optimization hint, and GSPMD still
+    propagates shardings through the auto axes.  jax exposes no public probe
+    for the manual axes in scope; this reads its axis environment.
     """
-    try:
-        from jax._src import core as _core
+    from jax._src import core as _core
 
-        return bool(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return False
+    return bool(_core.get_axis_env().axis_sizes)
 
 
 def constrain(x, logical_axes):

@@ -34,7 +34,6 @@ from .ref import (
     topk_select_ref,
 )
 from .refine import MIXED_WIDEN, mixed_prune_keep
-from .runtime import default_interpret
 
 __all__ = [
     "bucket_kselect_op",
@@ -49,7 +48,6 @@ __all__ = [
     "merge_topk_lists_ref",
     "pairwise_dist_ref",
     "topk_select_ref",
-    "default_interpret",
     "get_scan_backend",
     "register_scan_backend",
     "scan_backend_names",

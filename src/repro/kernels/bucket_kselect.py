@@ -18,38 +18,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .refine import bucket_refine_step
-from .runtime import default_interpret
+from .runtime import pallas_call
 
 __all__ = ["bucket_kselect", "Q_TILE"]
 
 Q_TILE = 8
 
 
-def _make_kernel(k: int, num_bins: int, iters: int, c: int):
+def _make_kernel(k: int, num_bins: int, iters: int):
     def kernel(qx_ref, qy_ref, px_ref, py_ref, valid_ref, out_ref):
-        qx = qx_ref[:]
-        qy = qy_ref[:]
-        px = px_ref[:]
-        py = py_ref[:]
-        valid = valid_ref[:]
-        dx = qx[:, None] - px[None, :]
-        dy = qy[:, None] - py[None, :]
+        valid = valid_ref[:, :] != 0  # (1, C)
+        dx = qx_ref[:, :] - px_ref[:, :]  # (Q_TILE, 1) - (1, C)
+        dy = qy_ref[:, :] - py_ref[:, :]
         d2 = dx * dx + dy * dy
         big = jnp.asarray(jnp.inf, d2.dtype)
-        d2 = jnp.where(valid[None, :], d2, big)
-        n_valid = valid.astype(jnp.int32).sum()
+        d2 = jnp.where(valid, d2, big)
+        n_valid = jnp.sum(jnp.where(valid, 1.0, 0.0), axis=1, keepdims=True)
 
-        lo = jnp.min(d2, axis=1)
-        hi0 = jnp.max(jnp.where(valid[None, :], d2, -big), axis=1)
+        lo = jnp.min(d2, axis=1, keepdims=True)
+        hi0 = jnp.max(jnp.where(valid, d2, -big), axis=1, keepdims=True)
         hi = jnp.maximum(hi0, lo) * (1 + 1e-6) + 1e-30
-        kth = jnp.full((Q_TILE,), k, jnp.int32)
+        kth = jnp.full_like(lo, k)
 
         def body(_, state):
             lo, hi, kth = state
-            return bucket_refine_step(d2, lo, hi, kth, num_bins)
+            return bucket_refine_step((d2,), lo, hi, kth, num_bins)
 
         lo, hi, kth = jax.lax.fori_loop(0, iters, body, (lo, hi, kth))
-        out_ref[:] = jnp.where(n_valid < k, big, hi).astype(out_ref.dtype)
+        out_ref[:, :] = jnp.where(n_valid < k, big, hi)
 
     return kernel
 
@@ -73,25 +69,22 @@ def bucket_kselect(
 
     Guarantee: ``count(valid & d2 < r) >= min(k, n_valid)`` per query, with the
     excess bounded by one bucket width after ``iters`` refinements; rows with
-    fewer than k valid candidates return +inf.  ``interpret=None`` auto-detects
-    (compiled on TPU, interpreted elsewhere — see runtime.default_interpret).
+    fewer than k valid candidates return +inf.  ``interpret``: see
+    :func:`repro.kernels.runtime.pallas_call`.
     """
-    if interpret is None:
-        interpret = default_interpret()
     q, c = qx.shape[0], px.shape[0]
     assert q % Q_TILE == 0, q
-    grid = (q // Q_TILE,)
-    return pl.pallas_call(
-        _make_kernel(k, num_bins, iters, c),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((Q_TILE,), lambda i: (i,)),
-            pl.BlockSpec((Q_TILE,), lambda i: (i,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((Q_TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q,), jnp.float32),
+    col = pl.BlockSpec((Q_TILE, 1), lambda i: (i, 0))
+    row = pl.BlockSpec((1, c), lambda i: (0, 0))
+    out = pallas_call(
+        _make_kernel(k, num_bins, iters),
+        grid=(q // Q_TILE,),
+        in_specs=[col, col, row, row, row],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((q, 1), jnp.float32),
         interpret=interpret,
-    )(qx, qy, px, py, valid)
+    )(
+        qx.reshape(q, 1), qy.reshape(q, 1), px.reshape(1, c), py.reshape(1, c),
+        valid.astype(jnp.int32).reshape(1, c),
+    )
+    return out[:, 0]

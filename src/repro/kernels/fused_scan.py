@@ -27,28 +27,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .refine import bucket_refine_step, masked_argmin_rounds, mixed_prune_keep
-from .runtime import default_interpret
+from .runtime import pallas_call
 
 __all__ = ["fused_scan_merge", "Q_TILE"]
 
 Q_TILE = 8
 
 
-def _make_kernel(k: int, w: int, num_bins: int, iters: int, precision: str):
+def _make_kernel(k: int, num_bins: int, iters: int, precision: str):
     def kernel(
         qx_ref, qy_ref, cx_ref, cy_ref, cids_ref, valid_ref,
         best_d_ref, best_i_ref, out_d_ref, out_i_ref,
     ):
-        qx = qx_ref[:]  # (T,)
-        qy = qy_ref[:]
-        cx = cx_ref[:, :]  # (T, W)
-        cy = cy_ref[:, :]
-        cids = cids_ref[:, :]
-        valid = valid_ref[:, :]
+        qx = qx_ref[:, :]  # (T, 1)
+        qy = qy_ref[:, :]
+        valid = valid_ref[:, :] != 0  # (T, W)
         big = jnp.asarray(jnp.inf, jnp.float32)
 
-        dx = cx - qx[:, None]
-        dy = cy - qy[:, None]
+        dx = cx_ref[:, :] - qx
+        dy = cy_ref[:, :] - qy
         if precision == "mixed":
             # bf16 prefilter against the widened exact k-th boundary
             # (DESIGN.md §14): candidates strictly beyond the current k-th
@@ -57,40 +54,44 @@ def _make_kernel(k: int, w: int, num_bins: int, iters: int, precision: str):
             # VPU work, not an extra HBM pass.  Bitwise-neutral: the argmin
             # rounds still pick the exact k smallest of the survivors, and
             # no true top-k member (ties included) can be pruned.
-            valid = valid & mixed_prune_keep(dx, dy, best_d_ref[:, k - 1])
+            valid = valid & mixed_prune_keep(dx, dy, best_d_ref[:, k - 1:k])
         d2 = jnp.where(valid, dx * dx + dy * dy, big)  # (T, W) — stays in VMEM
 
-        all_d = jnp.concatenate([best_d_ref[:, :], d2], axis=1)  # (T, k+W)
-        all_i = jnp.concatenate([best_i_ref[:, :], cids], axis=1)
-        finite = ~jnp.isinf(all_d)
-        n_valid = finite.astype(jnp.int32).sum(axis=1)  # (T,)
+        # the merge population is the row [current list ‖ window], kept as
+        # its two blocks (no lane concatenation)
+        pop = (best_d_ref[:, :], d2)
+        n_valid = sum(
+            jnp.sum(jnp.where(d < big, 1.0, 0.0), axis=1, keepdims=True)
+            for d in pop
+        )  # (T, 1)
 
         # --- pillar 1: bucket refinement of the k-th-distance radius.
-        lo = jnp.min(all_d, axis=1)
-        hi0 = jnp.max(jnp.where(finite, all_d, -big), axis=1)
+        lo = jnp.minimum(*(jnp.min(d, axis=1, keepdims=True) for d in pop))
+        hi0 = jnp.maximum(*(
+            jnp.max(jnp.where(d < big, d, -big), axis=1, keepdims=True)
+            for d in pop
+        ))
         hi = jnp.maximum(hi0, lo) * (1 + 1e-6) + 1e-30
-        kth = jnp.full((Q_TILE,), k, jnp.int32)
+        kth = jnp.full_like(lo, k)
 
         def refine(_, state):
             lo, hi, kth = state
-            return bucket_refine_step(all_d, lo, hi, kth, num_bins)
+            return bucket_refine_step(pop, lo, hi, kth, num_bins)
 
-        flo, fhi, _ = jax.lax.fori_loop(0, iters, refine, (lo, hi, kth))
-        # The k-th element lies in [flo, fhi) up to float rounding of the bucket
-        # edges; one extra bucket width of slop makes the prune safely
-        # conservative (excess survivors cost nothing — the argmin rounds below
-        # still pick the exact k smallest).  The slop is floored at a relative
-        # epsilon: once the interval narrows below one ulp of its magnitude,
-        # ``lo + width`` rounds back onto ``lo`` and ``fhi - flo`` collapses to
-        # 0 — with massed duplicate ties at the k-th distance the collapsed
-        # ``fhi`` can land EXACTLY on the k-th value and a ``< radius`` prune
-        # would drop every tied member (caught by tests/test_properties.py).
-        slop = jnp.maximum(fhi - flo, fhi * 1e-6 + 1e-30)
-        radius = jnp.where(n_valid < k, big, fhi + slop)
-        d_sel = jnp.where(all_d < radius[:, None], all_d, big)
+        _, fhi, _ = jax.lax.fori_loop(0, iters, refine, (lo, hi, kth))
+        # the refinement keeps count(d < fhi) >= k (its edge-exact counting),
+        # so every true top-k member, ties at the k-th distance included,
+        # survives the prune; the argmin rounds pick the exact k smallest
+        radius = jnp.where(n_valid < k, big, fhi)
 
         # --- pillar 2: ascending materialization by masked argmin rounds.
-        out_d, out_i = masked_argmin_rounds(d_sel, all_i, k)
+        out_d, out_i = masked_argmin_rounds(
+            [
+                (jnp.where(d < radius, d, big), i)
+                for d, i in zip(pop, (best_i_ref[:, :], cids_ref[:, :]))
+            ],
+            k,
+        )
         out_d_ref[:, :] = out_d
         out_i_ref[:, :] = out_i
 
@@ -111,24 +112,24 @@ def fused_scan_merge(
 ):
     """(Q,) queries x (Q, W) per-query windows x (Q, k) lists -> merged lists.
 
-    Semantics match the unfused dense path exactly (up to k-th-distance ties):
-    ``merge(best, window)`` = k smallest of the union, ascending, (-1, inf)
-    padded.  Q must be a multiple of Q_TILE (wrappers pad).
+    Semantics match the unfused dense path bit for bit (canonical
+    ``(d², id)`` order, lowest id first among ties): ``merge(best, window)``
+    = k smallest of the union, ascending, (-1, inf) padded.  Q must be a
+    multiple of Q_TILE (wrappers pad).
     ``precision="mixed"`` adds the in-VMEM bf16 widened-radius prefilter —
     bitwise-identical output (tests/test_properties.py fuzzes the parity).
+    ``interpret=None`` compiles for the TPU and interprets where lowered for
+    the CPU (:func:`repro.kernels.runtime.pallas_call`).
     """
-    if interpret is None:
-        interpret = default_interpret()
     q, w = cx.shape
     assert q % Q_TILE == 0, q
-    grid = (q // Q_TILE,)
     row = lambda i: (i, 0)
-    out_d, out_i = pl.pallas_call(
-        _make_kernel(k, w, num_bins, iters, precision),
-        grid=grid,
+    out_d, out_i = pallas_call(
+        _make_kernel(k, num_bins, iters, precision),
+        grid=(q // Q_TILE,),
         in_specs=[
-            pl.BlockSpec((Q_TILE,), lambda i: (i,)),
-            pl.BlockSpec((Q_TILE,), lambda i: (i,)),
+            pl.BlockSpec((Q_TILE, 1), row),
+            pl.BlockSpec((Q_TILE, 1), row),
             pl.BlockSpec((Q_TILE, w), row),
             pl.BlockSpec((Q_TILE, w), row),
             pl.BlockSpec((Q_TILE, w), row),
@@ -145,5 +146,8 @@ def fused_scan_merge(
             jax.ShapeDtypeStruct((q, k), jnp.int32),
         ],
         interpret=interpret,
-    )(qx, qy, cx, cy, cids, valid, best_d, best_i)
+    )(
+        qx.reshape(q, 1), qy.reshape(q, 1), cx, cy, cids,
+        valid.astype(jnp.int32), best_d, best_i,
+    )
     return out_d, out_i

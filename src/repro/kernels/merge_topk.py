@@ -37,18 +37,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .refine import masked_argmin_rounds
-from .runtime import default_interpret
+from .runtime import pallas_call
 
 __all__ = ["merge_topk_lists", "merge_topk_multi", "Q_TILE"]
 
 Q_TILE = 8
 
 
-def _make_multi_kernel(k: int, c: int):
+def _make_multi_kernel(k: int):
     def kernel(d_ref, i_ref, out_d_ref, out_i_ref):
-        out_d, out_i = masked_argmin_rounds(
-            d_ref[:, :].astype(jnp.float32), i_ref[:, :], k
-        )
+        out_d, out_i = masked_argmin_rounds([(d_ref[:, :], i_ref[:, :])], k)
         out_d_ref[:, :] = out_d
         out_i_ref[:, :] = out_i
 
@@ -64,16 +62,14 @@ def merge_topk_multi(d_cat, i_cat, *, k: int, interpret: bool | None = None):
     the ``topk_select`` body over that row — k masked argmin rounds with the
     canonical lowest-id tie-break, so the output is bit-identical to folding
     the same lists through the binary ``merge_topk_lists`` tree.
-    Q must be a multiple of Q_TILE (the wrapper pads).
+    Q must be a multiple of Q_TILE (the wrapper pads); inputs are f32/int32.
     """
-    if interpret is None:
-        interpret = default_interpret()
     q, c = d_cat.shape
     assert q % Q_TILE == 0, q
     grid = (q // Q_TILE,)
     row = lambda i: (i, 0)
-    out_d, out_i = pl.pallas_call(
-        _make_multi_kernel(k, c),
+    out_d, out_i = pallas_call(
+        _make_multi_kernel(k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((Q_TILE, c), row),
@@ -92,11 +88,12 @@ def merge_topk_multi(d_cat, i_cat, *, k: int, interpret: bool | None = None):
     return out_d, out_i
 
 
-def _make_kernel(k: int, ca: int, cb: int):
+def _make_kernel(k: int):
     def kernel(da_ref, ia_ref, db_ref, ib_ref, out_d_ref, out_i_ref):
-        d = jnp.concatenate([da_ref[:, :], db_ref[:, :]], axis=1)  # (T, ca+cb)
-        ids = jnp.concatenate([ia_ref[:, :], ib_ref[:, :]], axis=1)
-        out_d, out_i = masked_argmin_rounds(d.astype(jnp.float32), ids, k)
+        # the row [a ‖ b], kept as its two blocks (no lane concatenation)
+        out_d, out_i = masked_argmin_rounds(
+            [(da_ref[:, :], ia_ref[:, :]), (db_ref[:, :], ib_ref[:, :])], k
+        )
         out_d_ref[:, :] = out_d
         out_i_ref[:, :] = out_i
 
@@ -107,17 +104,16 @@ def _make_kernel(k: int, ca: int, cb: int):
 def merge_topk_lists(d_a, i_a, d_b, i_b, *, k: int, interpret: bool | None = None):
     """(Q, ka)+(Q, kb) ascending lists -> (Q, k) merged ascending list.
 
-    Q must be a multiple of Q_TILE (``ops.merge_topk_lists_op`` pads).
+    Q must be a multiple of Q_TILE (``ops.merge_topk_lists_op`` pads and
+    casts to f32/int32).
     """
-    if interpret is None:
-        interpret = default_interpret()
     q, ca = d_a.shape
     cb = d_b.shape[1]
     assert q % Q_TILE == 0, q
     grid = (q // Q_TILE,)
     row = lambda i: (i, 0)
-    out_d, out_i = pl.pallas_call(
-        _make_kernel(k, ca, cb),
+    out_d, out_i = pallas_call(
+        _make_kernel(k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((Q_TILE, ca), row),

@@ -3,10 +3,9 @@
 Two things live here:
 
 1. **Padding wrappers** (``*_op``): pad ragged shapes to kernel tile multiples,
-   dispatch, slice back.  On non-TPU backends kernels run in ``interpret=True``
-   mode (the body executes as jnp on the host); on TPU the same calls compile
-   to Mosaic — see :func:`repro.kernels.runtime.default_interpret`.  Set
-   ``REPRO_PALLAS_INTERPRET=0/1`` to force either mode.
+   dispatch, slice back.  A program lowered for the TPU compiles every kernel
+   to Mosaic; one lowered for the CPU runs the kernel body in interpret mode
+   (as XLA ops on the host) — see :func:`repro.kernels.runtime.pallas_call`.
 
 2. **The scan-backend registry** (DESIGN.md §6): the pipeline's SCAN step —
    "merge one window of gathered candidates into each query's ascending result
@@ -242,7 +241,7 @@ register_scan_backend("dense_topk")(_lex_sort_merge)
 @register_scan_backend("fused_bucket")
 def _fused_bucket_merge(qpos, cpos, cids, valid, best_d, best_i, k: int,
                         precision: str = "fp32"):
-    """Fused Pallas kernel; auto-interprets off-TPU (runtime.default_interpret).
+    """Fused Pallas kernel; compiled on the TPU (runtime.pallas_call).
 
     ``precision`` rides into the kernel as a static: the mixed-mode prefilter
     runs on the VMEM-resident distance deltas, not as a separate pass.
@@ -297,7 +296,7 @@ def _dense_merge_lists(d_a, i_a, d_b, i_b, k: int):
 
 @register_merge_backend("fused_merge")
 def _fused_merge_lists(d_a, i_a, d_b, i_b, k: int):
-    """Pallas kernel; auto-interprets off-TPU (runtime.default_interpret)."""
+    """Pallas kernel; compiled on the TPU (runtime.pallas_call)."""
     return merge_topk_lists_op(d_a, i_a, d_b, i_b, k=k)
 
 

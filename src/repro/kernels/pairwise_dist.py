@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .runtime import default_interpret
+from .runtime import pallas_call
 
 __all__ = ["pairwise_dist", "Q_TILE", "C_TILE"]
 
@@ -27,40 +27,32 @@ C_TILE = 128
 
 
 def _kernel(qx_ref, qy_ref, px_ref, py_ref, valid_ref, out_ref):
-    qx = qx_ref[:]  # (Q_TILE,)
-    qy = qy_ref[:]
-    px = px_ref[:]  # (C_TILE,)
-    py = py_ref[:]
-    valid = valid_ref[:]
-    dx = qx[:, None] - px[None, :]
-    dy = qy[:, None] - py[None, :]
+    dx = qx_ref[:, :] - px_ref[:, :]  # (Q_TILE, 1) - (1, C_TILE)
+    dy = qy_ref[:, :] - py_ref[:, :]
     d2 = dx * dx + dy * dy
-    out_ref[:, :] = jnp.where(valid[None, :], d2, jnp.inf).astype(out_ref.dtype)
+    out_ref[:, :] = jnp.where(valid_ref[:, :] != 0, d2, jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pairwise_dist(qx, qy, px, py, valid, *, interpret: bool | None = None):
     """(Q,),(Q,),(C,),(C,),(C,)bool -> (Q, C) f32 masked squared distances.
 
-    Q must be a multiple of Q_TILE and C of C_TILE (wrappers pad); ``interpret``
-    runs the kernel body on CPU for validation (None = auto-detect).
+    Q must be a multiple of Q_TILE and C of C_TILE (wrappers pad); queries
+    enter as (Q, 1) columns and candidates as (1, C) rows.  ``interpret``:
+    see :func:`repro.kernels.runtime.pallas_call`.
     """
-    if interpret is None:
-        interpret = default_interpret()
     q, c = qx.shape[0], px.shape[0]
     assert q % Q_TILE == 0 and c % C_TILE == 0, (q, c)
-    grid = (q // Q_TILE, c // C_TILE)
-    return pl.pallas_call(
+    col = pl.BlockSpec((Q_TILE, 1), lambda i, j: (i, 0))
+    row = pl.BlockSpec((1, C_TILE), lambda i, j: (0, j))
+    return pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((Q_TILE,), lambda i, j: (i,)),
-            pl.BlockSpec((Q_TILE,), lambda i, j: (i,)),
-            pl.BlockSpec((C_TILE,), lambda i, j: (j,)),
-            pl.BlockSpec((C_TILE,), lambda i, j: (j,)),
-            pl.BlockSpec((C_TILE,), lambda i, j: (j,)),
-        ],
+        grid=(q // Q_TILE, c // C_TILE),
+        in_specs=[col, col, row, row, row],
         out_specs=pl.BlockSpec((Q_TILE, C_TILE), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, c), jnp.float32),
         interpret=interpret,
-    )(qx, qy, px, py, valid)
+    )(
+        qx.reshape(q, 1), qy.reshape(q, 1), px.reshape(1, c), py.reshape(1, c),
+        valid.astype(jnp.int32).reshape(1, c),
+    )

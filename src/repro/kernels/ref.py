@@ -11,6 +11,7 @@ import jax.numpy as jnp
 __all__ = [
     "pairwise_dist_ref",
     "bucket_kselect_ref",
+    "bucket_refine_ref",
     "topk_select_ref",
     "merge_topk_lists_ref",
 ]
@@ -41,29 +42,38 @@ def bucket_kselect_ref(qx, qy, px, py, valid, *, k: int, num_bins: int, iters: i
     hi = jnp.maximum(hi0, lo) * (1 + 1e-6) + 1e-30
     kth = jnp.full((d2.shape[0],), k, jnp.int32)
     for _ in range(iters):
-        width = jnp.maximum((hi - lo) / num_bins, 1e-30)
-        b = jnp.clip(
-            jnp.floor((d2 - lo[:, None]) / width[:, None]), 0, num_bins - 1
-        ).astype(jnp.int32)
-        in_range = (d2 >= lo[:, None]) & (d2 < hi[:, None])
-        hist = jnp.sum(
-            (b[:, :, None] == jnp.arange(num_bins)[None, None, :]) & in_range[:, :, None],
-            axis=1,
-        ).astype(jnp.int32)
-        cum = jnp.cumsum(hist, axis=1)
-        sel = (cum >= kth[:, None]).argmax(axis=1)
-        below = jnp.where(
-            sel > 0,
-            jnp.take_along_axis(cum, jnp.maximum(sel - 1, 0)[:, None], 1)[:, 0],
-            0,
-        )
-        # float guard: edge rounding can push the k-th element out of [lo, hi);
-        # keep the previous (still-valid) interval in that case (kernel mirror).
-        ok = cum[:, -1] >= kth
-        lo = jnp.where(ok, lo + sel * width, lo)
-        hi = jnp.where(ok, lo + width, hi)
-        kth = jnp.where(ok, kth - below, kth)
+        lo, hi, kth = bucket_refine_ref(d2, lo, hi, kth, num_bins)
     return jnp.where(n_valid < k, big, hi)
+
+
+def bucket_refine_ref(d2, lo, hi, kth, num_bins: int):
+    """One histogram level over (Q, C) ``d2``: the kernel's edge-exact step.
+
+    Bucket b holds ``e_b <= d < e_{b+1}`` with ``e_b = lo + b * width`` and
+    the last bucket ending at ``hi``; the refined interval is made of the
+    edge values the counts were taken against, so ``count(lo <= d < hi) >=
+    kth`` holds at every level.  If no bucket reaches kth (the row has
+    fewer in range), the interval is kept.
+    """
+    width = jnp.maximum((hi - lo) / num_bins, 1e-30)
+    b = jnp.arange(1, num_bins + 1, dtype=d2.dtype)
+    edges = (lo[:, None] + b[None, :] * width[:, None]).at[:, -1].set(hi)
+    in_range = (d2 >= lo[:, None]) & (d2 < hi[:, None])
+    cum = jnp.sum(
+        in_range[:, None, :] & (d2[:, None, :] < edges[:, :, None]), axis=2
+    )  # (Q, NB) running bucket counts
+    under = cum < kth[:, None]
+    sel = under.sum(axis=1)
+    ok = ~under[:, -1]
+    below = jnp.max(jnp.where(under, cum, 0), axis=1)
+    take = lambda j: jnp.take_along_axis(edges, j[:, None], 1)[:, 0]
+    new_lo = jnp.where(sel > 0, take(jnp.maximum(sel - 1, 0)), lo)
+    new_hi = take(jnp.minimum(sel, num_bins - 1))
+    return (
+        jnp.where(ok, new_lo, lo),
+        jnp.where(ok, new_hi, hi),
+        jnp.where(ok, kth - below, kth),
+    )
 
 
 def topk_select_ref(d2, ids, *, k: int):

@@ -1,22 +1,34 @@
-"""Interpret-mode auto-detection shared by every Pallas wrapper/kernel.
+"""Where a Pallas kernel runs: compiled on the TPU, interpreted on the CPU.
 
-Policy: compile to Mosaic when a TPU backend is actually present, fall back to
-``interpret=True`` (kernel body evaluated with jnp on the host) anywhere else,
-so the identical program runs in CI containers and on accelerators with no
-caller opt-in.  ``REPRO_PALLAS_INTERPRET=0/1`` force-overrides both ways (e.g.
-to debug a kernel body on TPU, or to exercise the compile path in a unit test).
+Every kernel of this package goes through :func:`pallas_call`.  The choice
+between Mosaic (the TPU kernel compiler) and the interpreter (the kernel
+body evaluated as ordinary XLA ops) is made when the program is *lowered*,
+from the platform it is lowered for — not from the process's default
+backend.  A program lowered for a TPU therefore always carries the compiled
+kernel (a ``tpu_custom_call`` in its HLO), including one compiled ahead of
+time from a CPU-only host for a described TPU topology; the interpreter is
+used only where the program is lowered for the CPU, which is where the
+tests run.
 """
 from __future__ import annotations
 
-import os
-
 import jax
+from jax.experimental import pallas as pl
 
-__all__ = ["default_interpret"]
+__all__ = ["pallas_call"]
 
 
-def default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    return jax.default_backend() != "tpu"
+def pallas_call(kernel, *, interpret: bool | None = None, **kwargs):
+    """``pl.pallas_call`` with the compile-or-interpret choice made at lowering.
+
+    ``interpret=None`` stages both forms and lets ``lax.platform_dependent``
+    keep the interpreter for the CPU and the compiled kernel everywhere else
+    (the other branch is never lowered); ``True``/``False`` force one form.
+    """
+    if interpret is not None:
+        return pl.pallas_call(kernel, interpret=interpret, **kwargs)
+    compiled = pl.pallas_call(kernel, **kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+    return lambda *args: jax.lax.platform_dependent(
+        *args, cpu=interpreted, default=compiled
+    )

@@ -20,18 +20,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .refine import masked_argmin_rounds
-from .runtime import default_interpret
+from .runtime import pallas_call
 
 __all__ = ["topk_select", "Q_TILE"]
 
 Q_TILE = 8
 
 
-def _make_kernel(k: int, c: int):
+def _make_kernel(k: int):
     def kernel(d2_ref, ids_ref, out_d_ref, out_i_ref):
-        out_d, out_i = masked_argmin_rounds(
-            d2_ref[:, :].astype(jnp.float32), ids_ref[:, :], k
-        )
+        out_d, out_i = masked_argmin_rounds([(d2_ref[:, :], ids_ref[:, :])], k)
         out_d_ref[:, :] = out_d
         out_i_ref[:, :] = out_i
 
@@ -41,13 +39,11 @@ def _make_kernel(k: int, c: int):
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def topk_select(d2, ids, *, k: int, interpret: bool | None = None):
     """(Q, C) distances + (Q, C) ids -> ((Q, k) dists, (Q, k) ids), ascending."""
-    if interpret is None:
-        interpret = default_interpret()
     q, c = d2.shape
     assert q % Q_TILE == 0, q
     grid = (q // Q_TILE,)
-    out_d, out_i = pl.pallas_call(
-        _make_kernel(k, c),
+    out_d, out_i = pallas_call(
+        _make_kernel(k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((Q_TILE, c), lambda i: (i, 0)),

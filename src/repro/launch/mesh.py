@@ -5,9 +5,12 @@ jax device state (the dry-run sets XLA_FLAGS *before* any jax init).
 """
 from __future__ import annotations
 
+import os
+
 import jax
 
 __all__ = [
+    "forced_cpu_env",
     "make_production_mesh",
     "make_local_mesh",
     "make_query_mesh",
@@ -15,6 +18,23 @@ __all__ = [
     "make_spatial_mesh",
     "default_hybrid_shape",
 ]
+
+
+def forced_cpu_env(devices: int) -> dict:
+    """Environment for a child process on ``devices`` forced CPU devices.
+
+    ``JAX_PLATFORMS=cpu`` keeps the child off any accelerator: a TPU chip
+    belongs to one process, which may be the parent.  The XLA flag splits
+    the host into ``devices`` CPU devices; it only takes effect in a process
+    that has not initialized JAX yet.
+    """
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={devices}"
+    ).strip()
+    return env
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,6 +27,7 @@ from repro.api import KnnSession, ServiceSpec
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data import make_workload
 from repro.dist import use_rules
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import (
     decode_step,
@@ -223,7 +224,10 @@ def main(argv=None) -> int:
     m.add_argument("--model", type=int, default=1)
     m.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    return serve_knn(args) if args.mode == "knn" else serve_lm(args)
+    if args.mode == "knn":
+        use_compile_cache()
+        return serve_knn(args)
+    return serve_lm(args)
 
 
 if __name__ == "__main__":

@@ -17,6 +17,8 @@ from repro.kernels import (
     topk_select_ref,
     tree_merge_lists,
 )
+from repro.core import find_kdist
+from repro.kernels.refine import bucket_refine_step
 
 
 def _data(q, c, seed=0, dtype=np.float32):
@@ -52,6 +54,33 @@ def test_bucket_kselect_guarantee(q, c, k):
     if nv >= k:
         # selection is tight: at most a thin shell above k after 4 refinements
         assert cnt.mean() <= k * 1.5 + 2
+
+
+def _edge_heavy_rows(q=4096, c=64, seed=0):
+    """Squared distances on a 1/64 lattice at random per-row scales: many
+    values sit on or beside the refinement's bucket edges, where bucketing
+    by ``floor((d - lo) / width)`` and testing ``d < edge`` disagree."""
+    rng = np.random.default_rng(seed)
+    d2 = np.round(rng.uniform(0, 1, (q, c)) * 64) / 64
+    return (d2 * rng.uniform(0.5, 2, (q, 1))).astype(np.float32)
+
+
+@pytest.mark.parametrize("k", [8, 32])
+def test_bucket_refinement_keeps_k_below_radius(k):
+    """The refinement's guarantee ``count(d < radius) >= k`` holds on every
+    row, edge-sitting values included: in the jnp oracle (``find_kdist``)
+    and in the kernel-side step, iterated as the fused_bucket SCAN kernel
+    iterates it to get its prune radius (a row that broke it lost a true
+    neighbour from its merged list)."""
+    d2 = _edge_heavy_rows()
+    r = np.asarray(find_kdist(jnp.asarray(d2), jnp.ones(d2.shape, bool), k=k))
+    assert ((d2 < r[:, None]).sum(1) >= k).all()
+    lo = jnp.asarray(d2.min(1, keepdims=True))
+    hi = jnp.asarray(d2.max(1, keepdims=True)) * (1 + 1e-6) + 1e-30
+    kth = jnp.full_like(lo, k)
+    for _ in range(4):
+        lo, hi, kth = bucket_refine_step((jnp.asarray(d2),), lo, hi, kth, 32)
+    assert ((d2 < np.asarray(hi)).sum(1) >= k).all()
 
 
 @pytest.mark.parametrize("k", [1, 8, 32])

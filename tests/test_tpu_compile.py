@@ -1,0 +1,99 @@
+"""Ahead-of-time compiles for a described TPU v5e: the kernels the chip runs.
+
+The tests run on the CPU, where every Pallas kernel is interpreted.  These
+compile the served-path kernels with ``interpret=False`` for a ``v5e:2x2``
+topology described by ``jax.experimental.topologies`` (no chip needed), at
+the widths the service uses (query chunk 8192, window 256, k 32), so a
+kernel the TPU's compiler (Mosaic) refuses fails here.  The last test
+compiles a whole ``fused_bucket`` tick for that chip and requires the
+kernel in it: a program lowered for the TPU must carry it compiled
+(``tpu_custom_call``), never interpreted.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.api import KnnSession, ServiceSpec
+from repro.api import session as session_mod
+from repro.kernels import fused_scan, merge_topk
+
+Q, W, K = 8192, 256, 32
+F32, I32 = jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def tpu():
+    """The first device of a described ``v5e:2x2`` topology."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return topo.devices[0]
+
+
+def _compile_text(fn, tpu, *shapes):
+    on = jax.sharding.SingleDeviceSharding(tpu)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=on) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("precision", ["fp32", "mixed"])
+def test_fused_scan_merge_compiles_for_tpu(tpu, precision):
+    text = _compile_text(
+        lambda *a: fused_scan.fused_scan_merge(
+            *a, k=K, precision=precision, interpret=False),
+        tpu,
+        ((Q,), F32), ((Q,), F32), ((Q, W), F32), ((Q, W), F32),
+        ((Q, W), I32), ((Q, W), jnp.bool_), ((Q, K), F32), ((Q, K), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_merge_topk_lists_compiles_for_tpu(tpu):
+    text = _compile_text(
+        lambda *a: merge_topk.merge_topk_lists(*a, k=K, interpret=False),
+        tpu, ((Q, K), F32), ((Q, K), I32), ((Q, K), F32), ((Q, K), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_merge_topk_multi_compiles_for_tpu(tpu):
+    r = 4
+    text = _compile_text(
+        lambda *a: merge_topk.merge_topk_multi(*a, k=K, interpret=False),
+        tpu, ((Q, r * K), F32), ((Q, r * K), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_fused_bucket_tick_carries_the_compiled_kernel(tpu, monkeypatch):
+    """A whole tick, lowered for the chip with the kernels left to the
+    default (``interpret=None``): the kernel is compiled into it, while the
+    same session's CPU program interprets it."""
+    n = 2048
+    rng = np.random.default_rng(0)
+    pts = rng.uniform(0, 1000, (n, 2)).astype(np.float32)
+    sess = KnnSession(ServiceSpec(k=8, th_quad=64, l_max=5, window=64,
+                                  chunk=512, backend="fused_bucket"))
+    sess.ingest_objects(pts)
+    sess.register_queries(pts, np.arange(n, dtype=np.int32))
+    sess.submit().result()
+    assert "tpu_custom_call" not in sess.lower_tick().compile().as_text()
+
+    # the same arguments, as shapes on the described chip
+    step = session_mod._tick_step
+    on = jax.sharding.SingleDeviceSharding(tpu)
+
+    class OnTpu:
+        def lower(self, *args, **statics):
+            args = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on),
+                args)
+            return step.lower(*args, **statics)
+
+    monkeypatch.setattr(session_mod, "_tick_step", OnTpu())
+    assert "tpu_custom_call" in sess.lower_tick().compile().as_text()
