@@ -1,4 +1,4 @@
-"""Benchmark runner — one function per paper table/figure + kernels + roofline.
+"""Benchmark runner — one function per paper table/figure + kernels.
 
 Prints ``name,us_per_call,derived`` CSV rows.  Each sub-bench is importable and
 has a __main__ for full-size runs; this runner uses CPU-feasible defaults.
@@ -6,9 +6,7 @@ has a __main__ for full-size runs; this runner uses CPU-feasible defaults.
 ``--smoke`` runs a minutes-scale subset and writes ``BENCH_smoke.json``
 (queries/s + candidates/s per backend, engine tick latency, serving-mode
 rows) plus ``BENCH_serving_smoke.json`` (ingest x submit x collect mode,
-s6), ``ROOFLINE_stages_smoke.json`` (per-stage roofline: reindex/sweep/
-merge/collect bytes + FLOPs over measured counters) and
-``BENCH_skew_smoke.json`` (straggler gap: equal vs cost_balanced partitioner
+s6) and ``BENCH_skew_smoke.json`` (straggler gap: equal vs cost_balanced partitioner
 on a forced 8-device grid, s7) and ``BENCH_churn_smoke.json`` (rebuild vs
 incremental reindex under teleport churn, s8) — the per-PR perf trajectory
 artifacts consumed by CI.  The plain
@@ -70,16 +68,6 @@ def _smoke(out_path: str) -> None:
     rec["serving"] = s6_serving.run(
         objects=4_000, ticks=4, k=16, chunk=1024, window=128,
         out="BENCH_serving_smoke.json",
-    )
-
-    # per-stage roofline (reindex/sweep/merge/collect) at smoke size — the
-    # stage volume model over measured counters; full-size table comes from
-    # a plain `python benchmarks/roofline.py` run (ROOFLINE_stages.json)
-    from benchmarks import roofline
-
-    rec["roofline_stages"] = roofline.run(
-        objects=4_000, queries=1_024, ticks=3, chunk=1024,
-        out="ROOFLINE_stages_smoke.json",
     )
 
     # skew row: the partitioner seam's straggler-gap probe on a forced
@@ -186,11 +174,6 @@ def main() -> None:
 
     s9_soak.run(out="BENCH_soak.json")
     kernels.run(q=64, c=512, k=16)
-
-    # per-stage roofline at the committed serving config
-    from benchmarks import roofline
-
-    roofline.run(out="ROOFLINE_stages.json")
 
 
 if __name__ == "__main__":

@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from repro.core.ticks import TickResult
+from repro.tracing import span
 
 __all__ = ["QueryHandle", "TickHandle"]
 
@@ -73,8 +74,6 @@ class TickHandle:
         qids: np.ndarray,
         owner: np.ndarray,
         t0: float,
-        submit_s: float,
-        compile_s: float,
         rebuilt_pre: bool,
         collect: str = "full",
         agg=None,
@@ -92,8 +91,10 @@ class TickHandle:
         self._qids = qids
         self._owner = owner
         self._t0 = t0
-        self.submit_s = submit_s
-        self.compile_s = compile_s
+        # set by the session once the dispatch has returned: its host time,
+        # and the part of it JAX spent tracing and compiling
+        self.submit_s = 0.0
+        self.compile_s = 0.0
         self._rebuilt_pre = rebuilt_pre
         # how the step maintained the index this tick ("rebuild" |
         # "incremental" | "skip") — the session's scheduling decision,
@@ -150,7 +151,8 @@ class TickHandle:
             payload = [a for a in (self._nn_idx, self._nn_dist, self._agg)
                        if a is not None]
             if payload:
-                jax.block_until_ready(payload)
+                with span("tick.wait", tick=self.tick):
+                    jax.block_until_ready(payload)
         return self
 
     def _tick_result(self, nn_idx, nn_dist, shard_cand, shard_it,
@@ -200,6 +202,10 @@ class TickHandle:
         """
         if self._result is not None:
             return self._result
+        with span("tick.result", tick=self.tick):
+            return self._materialize(materialize)
+
+    def _materialize(self, materialize: bool) -> TickResult:
         self._session._finalize_through(self)
         nq = self._nq
         if not materialize:
@@ -219,10 +225,11 @@ class TickHandle:
             # materialization cost, not the device queue
             self.block_until_ready()
             tc = time.perf_counter()
-            agg, shard_cand, shard_it = jax.device_get(
-                (self._agg, self._aux.shard_candidates,
-                 self._aux.shard_iterations)
-            )
+            with span("tick.collect", tick=self.tick):
+                agg, shard_cand, shard_it = jax.device_get(
+                    (self._agg, self._aux.shard_candidates,
+                     self._aux.shard_iterations)
+                )
             self._result = self._tick_result(
                 None, None, shard_cand, shard_it,
                 collect_s=time.perf_counter() - tc, aggregates=agg,
@@ -232,10 +239,11 @@ class TickHandle:
             # timed after the compute drain (same decomposition as "stats")
             self.block_until_ready()
             tc = time.perf_counter()
-            nn_idx, nn_dist, shard_cand, shard_it = jax.device_get(
-                (self._nn_idx[:nq], self._nn_dist[:nq],
-                 self._aux.shard_candidates, self._aux.shard_iterations)
-            )
+            with span("tick.collect", tick=self.tick):
+                nn_idx, nn_dist, shard_cand, shard_it = jax.device_get(
+                    (self._nn_idx[:nq], self._nn_dist[:nq],
+                     self._aux.shard_candidates, self._aux.shard_iterations)
+                )
             self._result = self._tick_result(
                 nn_idx, nn_dist, shard_cand, shard_it,
                 collect_s=time.perf_counter() - tc,

@@ -56,17 +56,13 @@ from repro.core.ticks import (
     scatter_positions,
     shard_churn_over_budget,
 )
+from repro.tracing import compile_time, span
 
 from .handles import QueryHandle, TickHandle
 from .sink import StatsSink
 from .spec import ServiceSpec
 
 __all__ = ["KnnSession"]
-
-# compile_s attribution must mirror the PROCESS-global jit cache of
-# _tick_step, not per-session state: a second session with identical shapes
-# and statics hits the warm cache and must report compile_s = 0.
-_COMPILED_KEYS: set = set()
 
 
 class _QueryRegistry:
@@ -288,7 +284,8 @@ class KnnSession:
         positions = np.asarray(positions, np.float32)
         if positions.ndim != 2 or positions.shape[1] != 2:
             raise ValueError(f"positions must be (N, 2), got {positions.shape}")
-        self._positions = jnp.asarray(positions, jnp.float32)
+        with span("session.ingest", tick=self._tick):
+            self._positions = jnp.asarray(positions, jnp.float32)
         # whole buffer replaced, delta unknown: only a full refresh is safe
         self._positions_dirty = True
         self._pending_ids = None
@@ -306,6 +303,10 @@ class KnnSession:
         compiled program; duplicate ids within a batch resolve deterministically
         to the last observation.
         """
+        with span("session.ingest", tick=self._tick):
+            self._update_objects(ids, positions)
+
+    def _update_objects(self, ids, positions):
         if self._positions is None:
             raise RuntimeError("update_objects before ingest_objects: the "
                                "session has no object state to update")
@@ -455,7 +456,8 @@ class KnnSession:
         prepared next step — it must also maintain the padding rows, which
         clone the last active query for snapshot-path bit-identity.
         """
-        self._registry.update(handle, qpos)
+        with span("session.update_queries", tick=self._tick):
+            self._registry.update(handle, qpos)
 
     def drop_queries(self, handle: QueryHandle):
         """Remove a group; its rows stop being served from the next submit."""
@@ -545,6 +547,10 @@ class KnnSession:
         ``_leaf_levels`` op over equal pyramids), pinned by
         tests/test_maintenance.py.
         """
+        with span("session.rebuild", tick=self._tick):
+            self._rebuild()
+
+    def _rebuild(self):
         spec = self.spec
         if self._index is not None and not self._positions_dirty:
             self._index = rebuild_zmap(self._index)
@@ -592,13 +598,14 @@ class KnnSession:
         ``rebuild_factor`` × baseline rebuild the partition — from the newest
         object state — before the next dispatch.
         """
-        h._work = float(h._aux.stats.candidates)
-        h._iterations = int(h._aux.stats.iterations)
-        if self._work_at_build is None:
-            self._work_at_build = h._work
-        elif bool(h._should_rebuild):
-            self._build()
-            h._rebuilt_post = True
+        with span("session.finalize", tick=h.tick):
+            h._work = float(h._aux.stats.candidates)
+            h._iterations = int(h._aux.stats.iterations)
+            if self._work_at_build is None:
+                self._work_at_build = h._work
+            elif bool(h._should_rebuild):
+                self._build()
+                h._rebuilt_post = True
         h._finalized = True
 
     def _finalize_through(self, target: TickHandle | None = None):
@@ -636,8 +643,24 @@ class KnnSession:
         if self._registry.nq == 0:
             raise RuntimeError("submit with an empty query registry: "
                                "register_queries (or set_queries) first")
-        self._finalize_through()
-        t0 = time.perf_counter()
+        with span("session.submit", tick=self._tick):
+            self._finalize_through()
+            t0 = time.perf_counter()
+            with span("session.dispatch", tick=self._tick), \
+                    compile_time() as compiling:
+                h = self._dispatch(t0)
+            # submit_s covers the whole dispatch, INCLUDING any trace and
+            # compile that ran synchronously inside it; compile_s is that
+            # part, measured, which consumers subtract to get pure staging
+            # time (the serve layer's wall_s decomposition relies on this)
+            h.submit_s = time.perf_counter() - t0
+            h.compile_s = compiling.seconds
+        self._tick += 1
+        self._pending.append(h)
+        return h
+
+    def _dispatch(self, t0: float) -> TickHandle:
+        """Stage the registry and dispatch the tick step (and the sink)."""
         rebuilt_pre = False
         if self._index is None:
             self._build()
@@ -759,21 +782,7 @@ class KnnSession:
                 self._sink_state, nn_idx, nn_dist, self._index,
                 self._obj_bounds, jnp.int32(nq),
             )
-        submit_s = time.perf_counter() - t0
-        # submit_s covers the whole dispatch window, INCLUDING any first-
-        # compile that ran synchronously inside it — compile_s below is the
-        # submit-side attribution consumers subtract to get pure staging
-        # time (the serve layer's wall_s decomposition relies on this)
-        # key must mirror everything the jit cache keys on: shapes AND the
-        # statics (th_quad/l_max ride in the index pytree's meta fields)
-        key = (int(qpos_dev.shape[0]), self.num_objects, spec.k, spec.window,
-               spec.chunk, spec.l_max, spec.th_quad, spec.max_iters,
-               self.executor, self.plan, spec.collect, mode,
-               None if delta_ids_dev is None else int(delta_ids_dev.shape[0]),
-               qweight_dev is not None)
-        compile_s = submit_s if key not in _COMPILED_KEYS else 0.0
-        _COMPILED_KEYS.add(key)
-        h = TickHandle(
+        return TickHandle(
             session=self,
             tick=self._tick,
             nn_idx=nn_idx,
@@ -784,16 +793,11 @@ class KnnSession:
             qids=qids,
             owner=owner,
             t0=t0,
-            submit_s=submit_s,
-            compile_s=compile_s,
             rebuilt_pre=rebuilt_pre,
             collect=spec.collect,
             agg=agg,
             maintenance=mode,
         )
-        self._tick += 1
-        self._pending.append(h)
-        return h
 
     def _step_statics(self, mode: str) -> dict:
         """The static arguments of :func:`_tick_step` under this spec."""

@@ -27,6 +27,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import stage
+
 __all__ = ["TickAggregates", "SinkState", "ResultSink", "StatsSink"]
 
 
@@ -63,6 +65,7 @@ def init_sink_state(qp: int, k: int) -> SinkState:
 
 
 @partial(jax.jit, static_argnames=("num_shards", "use_bounds"))
+@stage("sink")
 def _stats_update(
     state: SinkState,
     nn_idx,

@@ -50,6 +50,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import stage
+
 from . import morton
 from .executor import QueryExecutor, resolve_executor
 from .quadtree import QuadtreeIndex
@@ -220,19 +222,21 @@ def _knn_sorted_impl(
         # ---------------- SCAN: one window of W candidates per scanning query.
         idx = st.s_cur[:, None] + st.off[:, None] + warange[None, :]
         in_window = st.scanning[:, None] & (idx < st.e_cur[:, None])
-        idxc = jnp.clip(idx, 0, n_obj - 1)
-        # NOTE: a fused (x,y,id) packed gather was tried and REFUTED — two
-        # narrow gathers beat one wide one here (EXPERIMENTS.md §Perf, P4)
-        cpos = index.pos[idxc]  # (Q, W, 2)
-        cids = index.ids[idxc]
-        # negative ids are sentinels: -2 external queries, -1 the padding rows
-        # the object-sharded plans append to even out shard slices
-        valid = in_window & (cids != qid[:, None]) & (cids >= 0)
-        # distance + k-selection merge: dispatched to the registered backend
-        # (result lists stay ascending; linear layout of Fig. 1)
-        best_d, best_i = executor.scan_merge(
-            qpos, cpos, cids, valid, st.best_d, st.best_i, k=k
-        )
+        with stage("gather"):
+            idxc = jnp.clip(idx, 0, n_obj - 1)
+            # NOTE: a fused (x,y,id) packed gather was tried and REFUTED —
+            # two narrow gathers beat one wide one (EXPERIMENTS.md §Perf, P4)
+            cpos = index.pos[idxc]  # (Q, W, 2)
+            cids = index.ids[idxc]
+        with stage("scan"):
+            # negative ids are sentinels: -2 external queries, -1 the padding
+            # rows the object-sharded plans append to even out shard slices
+            valid = in_window & (cids != qid[:, None]) & (cids >= 0)
+            # distance + k-selection merge: dispatched to the registered
+            # backend (result lists stay ascending; linear layout of Fig. 1)
+            best_d, best_i = executor.scan_merge(
+                qpos, cpos, cids, valid, st.best_d, st.best_i, k=k
+            )
         kth2 = best_d[:, k - 1]
 
         off2 = st.off + window
@@ -278,9 +282,10 @@ def _knn_sorted_impl(
             st.e_cur,
             jnp.zeros((nq,), bool),
         )
-        cl, cr, act_l, act_r, next_right, s_cur, e_cur, found_any = jax.lax.fori_loop(
-            0, max_nav, nav_body, nst
-        )
+        with stage("nav"):
+            cl, cr, act_l, act_r, next_right, s_cur, e_cur, found_any = (
+                jax.lax.fori_loop(0, max_nav, nav_body, nst)
+            )
 
         scanning = scanning | found_any
         off = jnp.where(found_any, 0, off)

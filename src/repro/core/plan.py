@@ -105,6 +105,7 @@ from repro.launch.mesh import (
     make_query_mesh,
     make_spatial_mesh,
 )
+from repro.tracing import stage
 
 from . import morton
 from .balance import EqualPartitioner, Partitioner, resolve_partitioner
@@ -472,6 +473,20 @@ def _stats_total(st_t: KnnStats) -> KnnStats:
 # --------------------------------------------------------------------------
 
 
+@stage("order")
+def _sort_queries(index, qpos, qid):
+    """The batch in Morton order: ``(order, inv, qpos[order], qid[order])``."""
+    order, inv = _sort_unsort(index, qpos)
+    return order, inv, qpos[order], qid[order]
+
+
+@stage("order")
+def _unsort(idx_s, d2_s, inv):
+    """Sorted-order results back in the caller's order, distances euclidean."""
+    return idx_s[inv], jnp.sqrt(d2_s[inv])
+
+
+@stage("sweep")
 def _chunked_sweep(index, qpos_s, qid_s, *, k, window, chunk, max_nav,
                    max_iters, executor):
     """``lax.map`` of the sorted-query program over fixed-shape chunks.
@@ -503,6 +518,7 @@ def _chunked_sweep(index, qpos_s, qid_s, *, k, window, chunk, max_nav,
     return idx_c.reshape(nq, k), d2_c.reshape(nq, k), stats, cq_c.reshape(nq)
 
 
+@stage("sweep")
 def _chunked_sweep_masked(index, qpos_s, qid_s, n_live_chunks, *, k, window,
                           chunk, max_nav, max_iters, executor):
     """:func:`_chunked_sweep` with a dynamic live-chunk count.
@@ -638,10 +654,11 @@ def _object_merge_local(origin, side, opos_r, oids_r, ocodes_r, gstarts,
             local, qp_l, qi_l, ownq_chunks, k=k, window=window, chunk=chunk,
             max_nav=max_nav, max_iters=max_iters, executor=executor,
         )
-    d2_all = jax.lax.all_gather(d2_l, "object")  # (R, Q_local, k)
-    idx_all = jax.lax.all_gather(idx_l, "object")
-    d2_m, idx_m = tree_merge_lists(d2_all, idx_all, k=k, merge=merge)
-    cq_m = jax.lax.psum(cq_l, "object")
+    with stage("merge"):
+        d2_all = jax.lax.all_gather(d2_l, "object")  # (R, Q_local, k)
+        idx_all = jax.lax.all_gather(idx_l, "object")
+        d2_m, idx_m = tree_merge_lists(d2_all, idx_all, k=k, merge=merge)
+        cq_m = jax.lax.psum(cq_l, "object")
     return idx_m, d2_m, _stats1(st), cq_m
 
 
@@ -711,9 +728,9 @@ class SinglePlan(ExecutionPlan):
             max_iters, executor, qweight=None, maintenance="rebuild"):
         del qweight  # no query-axis split: fairness weights have no seam here
         del maintenance  # no local trees: the global index is swept directly
-        order, inv = _sort_unsort(index, qpos)
+        order, inv, qpos_s, qid_s = _sort_queries(index, qpos, qid)
         idx_s, d2_s, stats, cq_s = _chunked_sweep(
-            index, qpos[order], qid[order], k=k, window=window, chunk=chunk,
+            index, qpos_s, qid_s, k=k, window=window, chunk=chunk,
             max_nav=max_nav, max_iters=max_iters, executor=executor,
         )
         qcost_next = _ema_next(qcost[order], cq_s, _EMA_ALPHA_DEFAULT)[inv]
@@ -724,7 +741,7 @@ class SinglePlan(ExecutionPlan):
             qcost_next=qcost_next,
             object_bounds=jnp.asarray([0, index.n_objects], jnp.int32),
         )
-        return idx_s[inv], jnp.sqrt(d2_s[inv]), aux
+        return (*_unsort(idx_s, d2_s, inv), aux)
 
     def describe(self) -> str:
         return "plan=single mesh=() devices=1"
@@ -770,8 +787,7 @@ class ShardedPlan(ExecutionPlan):
 
         # global Morton sort: shards stay spatially coherent AND chunk
         # boundaries coincide with the single plan's (bit-identity argument)
-        order, inv = _sort_unsort(index, qpos)
-        qpos_s, qid_s = qpos[order], qid[order]
+        order, inv, qpos_s, qid_s = _sort_queries(index, qpos, qid)
         obj_bounds = jnp.asarray([0, index.n_objects], jnp.int32)
         alpha = getattr(self.partitioner, "ema_alpha", _EMA_ALPHA_DEFAULT)
 
@@ -830,7 +846,7 @@ class ShardedPlan(ExecutionPlan):
             qcost_next=qcost_next,
             object_bounds=obj_bounds,
         )
-        return idx_s[inv], jnp.sqrt(d2_s[inv]), aux
+        return (*_unsort(idx_s, d2_s, inv), aux)
 
     def describe(self) -> str:
         return (
@@ -883,8 +899,7 @@ class ObjectShardedPlan(ExecutionPlan):
             out1_spec = rules.spec(("object",))
         repl_spec = P()
 
-        order, inv = _sort_unsort(index, qpos)
-        qpos_s, qid_s = qpos[order], qid[order]
+        order, inv, qpos_s, qid_s = _sort_queries(index, qpos, qid)
         capo = self.partitioner.object_capacity(
             index.n_objects, self.num_devices
         )
@@ -932,7 +947,7 @@ class ObjectShardedPlan(ExecutionPlan):
             qcost_next=qcost_next,
             object_bounds=bo,
         )
-        return idx_s[inv], jnp.sqrt(d2_s[inv]), aux
+        return (*_unsort(idx_s, d2_s, inv), aux)
 
     def describe(self) -> str:
         return (
@@ -993,8 +1008,7 @@ class HybridPlan(ExecutionPlan):
         out2_spec = P(("query", "object"), None)
         out1_spec = P(("query", "object"))
 
-        order, inv = _sort_unsort(index, qpos)
-        qpos_s, qid_s = qpos[order], qid[order]
+        order, inv, qpos_s, qid_s = _sort_queries(index, qpos, qid)
         nq = qpos.shape[0]
         n_chunks = nq // chunk
         capq = self.partitioner.query_capacity(n_chunks, qd)
@@ -1052,7 +1066,7 @@ class HybridPlan(ExecutionPlan):
             qcost_next=qcost_next,
             object_bounds=bo,
         )
-        return idx_s[inv], jnp.sqrt(d2_s[inv]), aux
+        return (*_unsort(idx_s, d2_s, inv), aux)
 
     def describe(self) -> str:
         return (

@@ -62,6 +62,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import stage
+
 from .balance import partitioner_names
 from .executor import QueryExecutor, available_backends, available_plans
 from .plan import ExecutionPlan
@@ -345,12 +347,15 @@ def _tick_step(
     by the session via snapshot upload, delta scatter, or the persistent
     padded query registry); this step never touches the host boundary.
     """
-    if maintenance == "rebuild":
-        index = reindex_objects(index, positions)
-    elif maintenance == "incremental":
-        index = reindex_objects_delta(index, positions, delta_ids, delta_old_pos)
-    elif maintenance != "skip":
-        raise ValueError(f"unknown step maintenance mode {maintenance!r}")
+    with stage("reindex"):
+        if maintenance == "rebuild":
+            index = reindex_objects(index, positions)
+        elif maintenance == "incremental":
+            index = reindex_objects_delta(
+                index, positions, delta_ids, delta_old_pos
+            )
+        elif maintenance != "skip":
+            raise ValueError(f"unknown step maintenance mode {maintenance!r}")
     # the mode rides into the plan (still static): under "incremental" and
     # "skip" the index's sorted order/pyramid are current for the buffer, so
     # the object-axis plans DERIVE their device-local trees from it instead
@@ -370,7 +375,8 @@ def _tick_step(
         qweight=qweight,
         maintenance=maintenance,
     )
-    should_rebuild = aux.stats.candidates > rebuild_factor * work_at_build
+    with stage("drift"):
+        should_rebuild = aux.stats.candidates > rebuild_factor * work_at_build
     return index, nn_idx, nn_dist, aux, should_rebuild
 
 

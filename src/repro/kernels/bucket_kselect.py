@@ -78,6 +78,7 @@ def bucket_kselect(
     row = pl.BlockSpec((1, c), lambda i: (0, 0))
     out = pallas_call(
         _make_kernel(k, num_bins, iters),
+        name="bucket_kselect",
         grid=(q // Q_TILE,),
         in_specs=[col, col, row, row, row],
         out_specs=col,

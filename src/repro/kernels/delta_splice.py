@@ -8,7 +8,7 @@ each element's output position is its own run offset plus the count of
 smaller elements in the other run — a vectorized binary search
 (O((N + Δ) log)) followed by one scatter per payload array.  That replaces
 the O(N log N) comparison sort that dominates the rebuild path's reindex
-stage (benchmarks/roofline.py models both).
+stage.
 
 Keys are *pairs*: the quadtree's canonical object order is lexicographic
 ``(morton code, object id)`` — what a stable ``argsort`` over the

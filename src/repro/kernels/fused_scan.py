@@ -126,6 +126,7 @@ def fused_scan_merge(
     row = lambda i: (i, 0)
     out_d, out_i = pallas_call(
         _make_kernel(k, num_bins, iters, precision),
+        name="fused_scan",
         grid=(q // Q_TILE,),
         in_specs=[
             pl.BlockSpec((Q_TILE, 1), row),

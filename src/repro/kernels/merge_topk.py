@@ -70,6 +70,7 @@ def merge_topk_multi(d_cat, i_cat, *, k: int, interpret: bool | None = None):
     row = lambda i: (i, 0)
     out_d, out_i = pallas_call(
         _make_multi_kernel(k),
+        name="merge_topk_multi",
         grid=grid,
         in_specs=[
             pl.BlockSpec((Q_TILE, c), row),
@@ -114,6 +115,7 @@ def merge_topk_lists(d_a, i_a, d_b, i_b, *, k: int, interpret: bool | None = Non
     row = lambda i: (i, 0)
     out_d, out_i = pallas_call(
         _make_kernel(k),
+        name="merge_topk_lists",
         grid=grid,
         in_specs=[
             pl.BlockSpec((Q_TILE, ca), row),

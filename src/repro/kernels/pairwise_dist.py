@@ -47,6 +47,7 @@ def pairwise_dist(qx, qy, px, py, valid, *, interpret: bool | None = None):
     row = pl.BlockSpec((1, C_TILE), lambda i, j: (0, j))
     return pallas_call(
         _kernel,
+        name="pairwise_dist",
         grid=(q // Q_TILE, c // C_TILE),
         in_specs=[col, col, row, row, row],
         out_specs=pl.BlockSpec((Q_TILE, C_TILE), lambda i, j: (i, j)),

@@ -18,13 +18,17 @@ from jax.experimental import pallas as pl
 __all__ = ["pallas_call"]
 
 
-def pallas_call(kernel, *, interpret: bool | None = None, **kwargs):
+def pallas_call(kernel, *, name: str, interpret: bool | None = None,
+                **kwargs):
     """``pl.pallas_call`` with the compile-or-interpret choice made at lowering.
 
-    ``interpret=None`` stages both forms and lets ``lax.platform_dependent``
-    keep the interpreter for the CPU and the compiled kernel everywhere else
-    (the other branch is never lowered); ``True``/``False`` force one form.
+    ``name`` is the kernel's stable name, the one a profiler trace and the
+    compiled program show it under.  ``interpret=None`` stages both forms
+    and lets ``lax.platform_dependent`` keep the interpreter for the CPU and
+    the compiled kernel everywhere else (the other branch is never lowered);
+    ``True``/``False`` force one form.
     """
+    kwargs["name"] = name
     if interpret is not None:
         return pl.pallas_call(kernel, interpret=interpret, **kwargs)
     compiled = pl.pallas_call(kernel, **kwargs)

@@ -44,6 +44,7 @@ def topk_select(d2, ids, *, k: int, interpret: bool | None = None):
     grid = (q // Q_TILE,)
     out_d, out_i = pallas_call(
         _make_kernel(k),
+        name="topk_select",
         grid=grid,
         in_specs=[
             pl.BlockSpec((Q_TILE, c), lambda i: (i, 0)),

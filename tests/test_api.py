@@ -492,6 +492,86 @@ def test_compile_time_split_from_wall_time():
     assert e2.compile_s == 0.0
 
 
+def test_compile_s_is_measured_for_a_new_padded_capacity():
+    """compile_s comes from JAX's own compile events: a second session whose
+    registry pads to a capacity no program was built for reports it on its
+    first tick, and only there."""
+    rng = np.random.default_rng(2)
+    pts = rng.uniform(0, 22_500, (400, 2)).astype(np.float32)
+    spec = _spec(k=5, window=32, chunk=96)
+    first = KnnSession(spec)
+    first.ingest_objects(pts)
+    first.register_queries(pts[:33])  # pads to one chunk
+    first.submit().result()
+    second = KnnSession(spec)
+    second.ingest_objects(pts)
+    second.register_queries(pts[:150])  # pads to two chunks: a new shape
+    r0 = second.submit().result()
+    r1 = second.submit().result()
+    assert r0.compile_s > 0.0
+    assert r1.compile_s == 0.0
+
+
+def _trace_spans(path):
+    """``{name: [tick, ...]}`` of the ``knn.`` spans in a recorded trace."""
+    from pathlib import Path
+
+    data = jax.profiler.ProfileData.from_file(
+        str(next(Path(path).rglob("*.xplane.pb"))))
+    out = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("knn."):
+                    stats = dict(ev.stats)
+                    out.setdefault(ev.name, []).append(
+                        (int(stats["tick"]), ev.start_ns,
+                         ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_one_tick_emits_the_session_spans(tmp_path):
+    """One tick of the closed loop (one tick in flight) writes exactly these
+    host spans into the profiler's trace, each with its tick number;
+    finalize and dispatch nest in submit, collect in result."""
+    rng = np.random.default_rng(3)
+    pts = rng.uniform(0, 22_500, (500, 2)).astype(np.float32)
+    sess = KnnSession(_spec())
+    sess.ingest_objects(pts)
+    hq = sess.register_queries(pts[:40], np.arange(40))
+    prev = sess.submit()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        sess.ingest_objects(pts[::-1].copy())
+        prev.block_until_ready()
+        sess.update_queries(hq, pts[40:80])
+        nxt = sess.submit()
+        prev.result()
+    finally:
+        jax.profiler.stop_trace()
+    nxt.result()
+    spans = _trace_spans(tmp_path)
+    assert set(spans) == {
+        "knn.session.ingest", "knn.session.update_queries",
+        "knn.session.submit", "knn.session.finalize", "knn.session.dispatch",
+        "knn.tick.wait", "knn.tick.result", "knn.tick.collect",
+    }
+    assert [t for t, _, _ in spans["knn.session.submit"]] == [1]
+    assert [t for t, _, _ in spans["knn.session.finalize"]] == [0]
+    assert {t for t, _, _ in spans["knn.tick.wait"]} == {0}
+
+    def inside(child, parent):
+        (_, s, e), = spans[child]
+        (_, ps, pe), = spans[parent]
+        return ps <= s and e <= pe
+
+    assert inside("knn.session.finalize", "knn.session.submit")
+    assert inside("knn.session.dispatch", "knn.session.submit")
+    assert inside("knn.tick.collect", "knn.tick.result")
+
+
 # ------------------------------------------------------- drift rebuild
 
 def test_drift_rebuild_through_delta_path():

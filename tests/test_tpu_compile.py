@@ -4,11 +4,15 @@ The tests run on the CPU, where every Pallas kernel is interpreted.  These
 compile the served-path kernels with ``interpret=False`` for a ``v5e:2x2``
 topology described by ``jax.experimental.topologies`` (no chip needed), at
 the widths the service uses (query chunk 8192, window 256, k 32), so a
-kernel the TPU's compiler (Mosaic) refuses fails here.  The last test
+kernel the TPU's compiler (Mosaic) refuses fails here; each kernel must
+show in the compiled program under its stable name, the instruction name a
+profiler trace shows it by.  The last test
 compiles a whole ``fused_bucket`` tick for that chip and requires the
 kernel in it: a program lowered for the TPU must carry it compiled
 (``tpu_custom_call``), never interpreted.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +20,13 @@ import pytest
 
 from repro.api import KnnSession, ServiceSpec
 from repro.api import session as session_mod
-from repro.kernels import fused_scan, merge_topk
+from repro.kernels import (
+    bucket_kselect,
+    fused_scan,
+    merge_topk,
+    pairwise_dist,
+    topk_select,
+)
 
 Q, W, K = 8192, 256, 32
 F32, I32 = jnp.float32, jnp.int32
@@ -41,6 +51,12 @@ def _compile_text(fn, tpu, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _compiled_kernel(text, name):
+    """Is there a compiled Pallas kernel named ``name`` in ``text``?"""
+    pattern = rf"%{name}(\.\d+)? = .*custom_call_target=\"tpu_custom_call\""
+    return re.search(pattern, text) is not None
+
+
 @pytest.mark.parametrize("precision", ["fp32", "mixed"])
 def test_fused_scan_merge_compiles_for_tpu(tpu, precision):
     text = _compile_text(
@@ -50,7 +66,7 @@ def test_fused_scan_merge_compiles_for_tpu(tpu, precision):
         ((Q,), F32), ((Q,), F32), ((Q, W), F32), ((Q, W), F32),
         ((Q, W), I32), ((Q, W), jnp.bool_), ((Q, K), F32), ((Q, K), I32),
     )
-    assert "tpu_custom_call" in text
+    assert _compiled_kernel(text, "fused_scan")
 
 
 def test_merge_topk_lists_compiles_for_tpu(tpu):
@@ -58,7 +74,7 @@ def test_merge_topk_lists_compiles_for_tpu(tpu):
         lambda *a: merge_topk.merge_topk_lists(*a, k=K, interpret=False),
         tpu, ((Q, K), F32), ((Q, K), I32), ((Q, K), F32), ((Q, K), I32),
     )
-    assert "tpu_custom_call" in text
+    assert _compiled_kernel(text, "merge_topk_lists")
 
 
 def test_merge_topk_multi_compiles_for_tpu(tpu):
@@ -67,7 +83,28 @@ def test_merge_topk_multi_compiles_for_tpu(tpu):
         lambda *a: merge_topk.merge_topk_multi(*a, k=K, interpret=False),
         tpu, ((Q, r * K), F32), ((Q, r * K), I32),
     )
-    assert "tpu_custom_call" in text
+    assert _compiled_kernel(text, "merge_topk_multi")
+
+
+C = 1024  # a shared candidate window of the brute-force kernels
+
+
+@pytest.mark.parametrize("name, fn, shapes", [
+    ("bucket_kselect",
+     lambda *a: bucket_kselect.bucket_kselect(*a, k=K, interpret=False),
+     (((Q,), F32), ((Q,), F32), ((C,), F32), ((C,), F32),
+      ((C,), jnp.bool_))),
+    ("pairwise_dist",
+     lambda *a: pairwise_dist.pairwise_dist(*a, interpret=False),
+     (((Q,), F32), ((Q,), F32), ((C,), F32), ((C,), F32),
+      ((C,), jnp.bool_))),
+    ("topk_select",
+     lambda *a: topk_select.topk_select(*a, k=K, interpret=False),
+     (((Q, C), F32), ((Q, C), I32))),
+])
+def test_other_kernels_compile_for_tpu_under_their_names(tpu, name, fn,
+                                                          shapes):
+    assert _compiled_kernel(_compile_text(fn, tpu, *shapes), name)
 
 
 def test_fused_bucket_tick_carries_the_compiled_kernel(tpu, monkeypatch):
