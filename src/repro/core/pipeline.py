@@ -11,7 +11,8 @@ tasks on the fly and sorts them by weight to balance GPU SMs.  Under XLA we run 
 **masked dense iteration**: all queries advance in lockstep inside one
 ``lax.while_loop``; per iteration each query either
   * SCANs one fixed-width window of ``W`` candidate objects from its current leaf
-    (gather -> masked distance tile -> top-k merge), or
+    (row fetch by DMA, ``kernels/window_fetch.py`` -> masked distance tile ->
+    top-k merge), or
   * NAVigates the *virtual full quadtree* (arithmetic-only, paper Sec. 4.2.2):
     up to ``max_nav`` aligned-block jumps that skip empty (count-pyramid) or
     pruned (box farther than kth) regions in O(4^a)-sized strides.
@@ -50,6 +51,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.window_fetch import row_tables, window_fetch
 from repro.tracing import stage
 
 from . import morton
@@ -70,6 +72,7 @@ class KnnStats(NamedTuple):
     iterations: jnp.ndarray  # () i32 — outer while-loop trips
     candidates: jnp.ndarray  # () i64-ish f32 — total candidate object slots scanned
     leaves_visited: jnp.ndarray  # () i32 — scheduled leaf scans (incl. own leaf)
+    windows_fetched: jnp.ndarray  # () i32 — lane windows fetched, over trips
 
 
 def zero_stats() -> KnnStats:
@@ -78,6 +81,7 @@ def zero_stats() -> KnnStats:
         iterations=jnp.int32(0),
         candidates=jnp.float32(0.0),
         leaves_visited=jnp.int32(0),
+        windows_fetched=jnp.int32(0),
     )
 
 
@@ -96,6 +100,7 @@ class _State(NamedTuple):
     it: jnp.ndarray  # () i32
     cand_q: jnp.ndarray  # (Q,) f32 — candidate slots scanned PER QUERY (cost model)
     leaves: jnp.ndarray  # () i32
+    fetched: jnp.ndarray  # () i32 — lanes that fetched a window, over trips
 
 
 def _nav_step(index: QuadtreeIndex, qx, qy, kth2, cursor, run, dir_r):
@@ -176,10 +181,14 @@ def _knn_sorted_impl(
     max_nav: int,
     max_iters: int,
     executor: QueryExecutor,
+    tables,
 ):
-    """k-NN for queries already sorted by Morton code (trace-level body)."""
+    """k-NN for queries already sorted by Morton code (trace-level body).
+
+    ``tables`` are the index's row tables for ``window``
+    (:func:`window_tables`), built once for every chunk swept over it.
+    """
     nq = qpos.shape[0]
-    n_obj = index.n_objects
     n_fine = index.n_fine
     l_max = index.l_max
     qx, qy = qpos[:, 0], qpos[:, 1]
@@ -208,9 +217,8 @@ def _knn_sorted_impl(
         it=jnp.int32(0),
         cand_q=jnp.zeros((nq,), jnp.float32),
         leaves=(e0 > s0).sum().astype(jnp.int32),
+        fetched=jnp.int32(0),
     )
-
-    warange = jnp.arange(window, dtype=jnp.int32)
 
     def live(st: _State):
         return st.scanning | st.act_l | st.act_r
@@ -220,14 +228,18 @@ def _knn_sorted_impl(
 
     def body(st: _State) -> _State:
         # ---------------- SCAN: one window of W candidates per scanning query.
-        idx = st.s_cur[:, None] + st.off[:, None] + warange[None, :]
-        in_window = st.scanning[:, None] & (idx < st.e_cur[:, None])
+        start = st.s_cur + st.off
         with stage("gather"):
-            idxc = jnp.clip(idx, 0, n_obj - 1)
-            # NOTE: a fused (x,y,id) packed gather was tried and REFUTED —
-            # two narrow gathers beat one wide one (EXPERIMENTS.md §Perf, P4)
-            cpos = index.pos[idxc]  # (Q, W, 2)
-            cids = index.ids[idxc]
+            # whole 128-wide rows per lane, by DMA; slot j holds object idx
+            cx, cy, cids, idx = window_fetch(
+                tables, start, st.scanning, window=window)
+            cpos = jnp.stack([cx, cy], axis=-1)  # (Q, Wf, 2)
+        stop = jnp.minimum(start + window, st.e_cur)
+        in_window = (
+            st.scanning[:, None]
+            & (idx >= start[:, None])
+            & (idx < stop[:, None])
+        )
         with stage("scan"):
             # negative ids are sentinels: -2 external queries, -1 the padding
             # rows the object-sharded plans append to even out shard slices
@@ -306,13 +318,23 @@ def _knn_sorted_impl(
             it=st.it + 1,
             cand_q=cand_q,
             leaves=leaves,
+            fetched=st.fetched + st.scanning.sum().astype(jnp.int32),
         )
 
     st = jax.lax.while_loop(cond, body, state)
     stats = KnnStats(
-        iterations=st.it, candidates=st.cand_q.sum(), leaves_visited=st.leaves
+        iterations=st.it,
+        candidates=st.cand_q.sum(),
+        leaves_visited=st.leaves,
+        windows_fetched=st.fetched,
     )
     return st.best_i, st.best_d, stats, st.cand_q
+
+
+def window_tables(index: QuadtreeIndex, window: int):
+    """The index's object store as the row tables the window fetch reads."""
+    with stage("gather"):
+        return row_tables(index.pos, index.ids, window)
 
 
 _knn_sorted = jax.jit(
@@ -381,7 +403,8 @@ def knn_query_batch(
     # spatial sort of queries (locality for z_map lookups & frontier coherence)
     order, inv = _sort_unsort(index, qpos)
     idx_s, d2_s, stats, _ = _knn_sorted(
-        index, qpos[order], qid[order], k, window, max_nav, max_iters, executor
+        index, qpos[order], qid[order], k, window, max_nav, max_iters,
+        executor, window_tables(index, window),
     )
     return idx_s[inv], jnp.sqrt(d2_s[inv]), stats
 

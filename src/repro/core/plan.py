@@ -114,6 +114,7 @@ from .pipeline import (
     _knn_sorted_impl,
     _resolve_max_nav,
     _sort_unsort,
+    window_tables,
     zero_stats,
 )
 from .quadtree import (
@@ -447,11 +448,12 @@ def _take_replica0(x, n_replicas: int):
 
 def _stats1(st: KnnStats) -> KnnStats:
     """Scalar stats -> (1,) arrays, the tiled per-shard out_spec unit."""
-    return KnnStats(
-        iterations=st.iterations.reshape(1),
-        candidates=st.candidates.reshape(1),
-        leaves_visited=st.leaves_visited.reshape(1),
-    )
+    return KnnStats(*(x.reshape(1) for x in st))
+
+
+def _stats_spec(spec) -> KnnStats:
+    """One ``shard_map`` spec for every :class:`KnnStats` counter."""
+    return KnnStats(*(spec,) * len(KnnStats._fields))
 
 
 def _stats_total(st_t: KnnStats) -> KnnStats:
@@ -461,11 +463,7 @@ def _stats_total(st_t: KnnStats) -> KnnStats:
     counters, so ``aux.stats.candidates == aux.shard_candidates.sum()``
     holds bitwise by construction.
     """
-    return KnnStats(
-        iterations=st_t.iterations.sum(),
-        candidates=st_t.candidates.sum(),
-        leaves_visited=st_t.leaves_visited.sum(),
-    )
+    return KnnStats(*(x.sum() for x in st_t))
 
 
 # --------------------------------------------------------------------------
@@ -499,22 +497,19 @@ def _chunked_sweep(index, qpos_s, qid_s, *, k, window, chunk, max_nav,
     """
     nq = qpos_s.shape[0]
     n_chunks = nq // chunk
+    tables = window_tables(index, window)  # once for every chunk
 
     def one_chunk(args):
         qp, qi = args
         return _knn_sorted_impl(
-            index, qp, qi, k, window, max_nav, max_iters, executor
+            index, qp, qi, k, window, max_nav, max_iters, executor, tables
         )
 
     idx_c, d2_c, stats_c, cq_c = jax.lax.map(
         one_chunk,
         (qpos_s.reshape(n_chunks, chunk, 2), qid_s.reshape(n_chunks, chunk)),
     )
-    stats = KnnStats(
-        iterations=stats_c.iterations.sum(),
-        candidates=stats_c.candidates.sum(),
-        leaves_visited=stats_c.leaves_visited.sum(),
-    )
+    stats = _stats_total(stats_c)
     return idx_c.reshape(nq, k), d2_c.reshape(nq, k), stats, cq_c.reshape(nq)
 
 
@@ -532,13 +527,14 @@ def _chunked_sweep_masked(index, qpos_s, qid_s, n_live_chunks, *, k, window,
     """
     nq = qpos_s.shape[0]
     n_chunks = nq // chunk
+    tables = window_tables(index, window)  # once for every chunk
 
     def one_chunk(args):
         qp, qi, live = args
 
         def real(_):
             return _knn_sorted_impl(
-                index, qp, qi, k, window, max_nav, max_iters, executor
+                index, qp, qi, k, window, max_nav, max_iters, executor, tables
             )
 
         def dead(_):
@@ -557,11 +553,7 @@ def _chunked_sweep_masked(index, qpos_s, qid_s, n_live_chunks, *, k, window,
         (qpos_s.reshape(n_chunks, chunk, 2), qid_s.reshape(n_chunks, chunk),
          live),
     )
-    stats = KnnStats(
-        iterations=stats_c.iterations.sum(),
-        candidates=stats_c.candidates.sum(),
-        leaves_visited=stats_c.leaves_visited.sum(),
-    )
+    stats = _stats_total(stats_c)
     return idx_c.reshape(nq, k), d2_c.reshape(nq, k), stats, cq_c.reshape(nq)
 
 
@@ -829,7 +821,7 @@ class ShardedPlan(ExecutionPlan):
             mesh=mesh,
             in_specs=(repl_spec, repl_spec, repl_spec, repl_spec),
             out_specs=(qpos_spec, qpos_spec,
-                       KnnStats(qvec_spec, qvec_spec, qvec_spec),
+                       _stats_spec(qvec_spec),
                        qvec_spec),
             axis_names={"query"},
             check_vma=False,
@@ -927,7 +919,7 @@ class ObjectShardedPlan(ExecutionPlan):
             mesh=mesh,
             in_specs=(repl_spec,) * 9,
             out_specs=(out2_spec, out2_spec,
-                       KnnStats(out1_spec, out1_spec, out1_spec), out1_spec),
+                       _stats_spec(out1_spec), out1_spec),
             axis_names={"object"},
             check_vma=False,
         )
@@ -1045,7 +1037,7 @@ class HybridPlan(ExecutionPlan):
             mesh=mesh,
             in_specs=(repl_spec,) * 10,
             out_specs=(out2_spec, out2_spec,
-                       KnnStats(out1_spec, out1_spec, out1_spec), out1_spec),
+                       _stats_spec(out1_spec), out1_spec),
             axis_names={"query", "object"},
             check_vma=False,
         )
@@ -1334,6 +1326,7 @@ def knn_query_batch_chunked(
         iterations=int(aux.stats.iterations),
         candidates=float(aux.stats.candidates),
         leaves_visited=int(aux.stats.leaves_visited),
+        windows_fetched=int(aux.stats.windows_fetched),
     )
     out = (np.asarray(ii[:nq]), np.asarray(dd[:nq]), stats)
     if with_aux:

@@ -12,13 +12,15 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # buffers, each a separate anonymous mmap; across the full suite the process
 # can accumulate tens of thousands of maps and cross vm.max_map_count
 # (default 65530), at which point XLA's next compile segfaults instead of
-# raising.  Dropping the executable caches between modules bounds the
-# accumulation — but it also recompiles everything the next module shares,
-# which is pure waste on machines nowhere near the limit.  So the drop is
-# GATED on actual proximity to the limit (see _near_map_count_limit;
-# DESIGN.md §16 documents the mechanism), overridable for debugging:
+# raising.  Dropping the executable caches bounds the accumulation — but it
+# also recompiles everything the next test shares, which is pure waste on
+# machines nowhere near the limit.  So the drop is GATED on actual
+# proximity to the limit (see _near_map_count_limit; DESIGN.md §16
+# documents the mechanism), and checked after every test, since one module
+# alone can compile past the limit (a property test adds ~10,000 maps),
+# overridable for debugging:
 #
-#   REPRO_JAX_CACHE_DROP=always  drop after every module (the old behavior)
+#   REPRO_JAX_CACHE_DROP=always  drop after every test
 #   REPRO_JAX_CACHE_DROP=never   never drop (reproduce the segfault)
 #   REPRO_JAX_CACHE_DROP=auto    drop only when near the map-count limit
 #                                (default)
@@ -51,7 +53,7 @@ def _near_map_count_limit() -> bool:
     return maps > _DROP_FRACTION * limit
 
 
-@pytest.fixture(autouse=True, scope="module")
+@pytest.fixture(autouse=True)
 def _drop_jax_executable_caches():
     yield
     mode = os.environ.get("REPRO_JAX_CACHE_DROP", "auto")

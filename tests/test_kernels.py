@@ -224,3 +224,39 @@ def test_tree_merge_composes_r_way_partition(backend, r):
         jnp.stack(parts_d), jnp.stack(parts_i), k=k, merge=backend)
     np.testing.assert_array_equal(np.asarray(got_d), np.asarray(full_d))
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(full_i))
+
+
+@pytest.mark.parametrize("n,window,q", [
+    (1000, 256, 200),  # n not a multiple of 128, Q not one of the lane tile
+    (100, 256, 13),  # n < W
+    (1024, 64, 128),
+    (300, 128, 37),
+])
+def test_window_fetch_rows_cover_each_window(n, window, q):
+    """Each scanning lane's fetched slots, masked as the sweep masks them
+    (``start <= slot < min(start + W, e)``), are ``table[start : min(start +
+    W, e)]`` in order; a lane that does not scan reads id -1 everywhere."""
+    from repro.kernels.window_fetch import row_tables, window_fetch
+
+    rng = np.random.default_rng(n + window + q)
+    pos = rng.uniform(0, 1000, (n, 2)).astype(np.float32)
+    ids = rng.permutation(n).astype(np.int32)
+    edges = [s for s in (0, 128, 127, n - 1) if s < n]
+    start = np.concatenate([edges, rng.integers(0, n, q - len(edges))])
+    start = start.astype(np.int32)
+    stop = rng.integers(start + 1, n + 1).astype(np.int32)  # the leaf's end
+    scanning = rng.random(q) < 0.7
+    scanning[: len(edges)] = True
+    cx, cy, cids, slot = map(np.asarray, window_fetch(
+        row_tables(jnp.asarray(pos), jnp.asarray(ids), window),
+        jnp.asarray(start), jnp.asarray(scanning), window=window))
+    for lane in range(q):
+        if not scanning[lane]:
+            assert (cids[lane] == -1).all()
+            assert np.isfinite(cx[lane]).all() and np.isfinite(cy[lane]).all()
+            continue
+        s, e = start[lane], min(start[lane] + window, stop[lane])
+        keep = (slot[lane] >= s) & (slot[lane] < e)
+        np.testing.assert_array_equal(cids[lane][keep], ids[s:e])
+        np.testing.assert_array_equal(cx[lane][keep], pos[s:e, 0])
+        np.testing.assert_array_equal(cy[lane][keep], pos[s:e, 1])

@@ -106,3 +106,58 @@ def test_chunked_driver_matches():
     ii_a, dd_a, _ = knn_query_batch(idx, jnp.asarray(pts), jnp.asarray(qid), k=8)
     ii_b, dd_b, _ = knn_query_batch_chunked(idx, pts, qid, k=8, chunk=256)
     np.testing.assert_allclose(np.asarray(dd_a), dd_b, rtol=1e-6)
+
+
+def _element_gather_fetch(tables, start, scanning, *, window):
+    """The sweep's window as the element gather read it: W slots from
+    ``start``, one element each, slot j holding object ``start + j``."""
+    xt, yt, it = (t.reshape(-1) for t in tables)
+    idx = start[:, None] + jnp.arange(window, dtype=jnp.int32)
+    return xt[idx], yt[idx], it[idx], idx
+
+
+@pytest.mark.parametrize("plan", ["single", "hybrid"])
+@pytest.mark.parametrize("window,chunk", [(64, 128), (128, 512), (256, 256)])
+def test_window_fetch_sweep_matches_element_gather(monkeypatch, plan, window,
+                                                   chunk):
+    """The row fetch leaves the sweep bitwise as the element gather had it:
+    the same candidate set per lane and trip, so the same lists, distances,
+    candidates and trips; ``hybrid`` on a (1, 1) mesh runs the object-tail
+    padding path.  ``windows_fetched`` counts at most one window per lane
+    and trip."""
+    import jax
+
+    from repro.core import pipeline
+
+    w = make_workload(1500, "gaussian", seed=4, hotspots=5)
+    pts = w.positions()
+    qpos, qid = w.query_batch()
+    idx = build_index(jnp.asarray(pts), jnp.zeros(2), 22_500.0, l_max=6,
+                      th_quad=24)
+    kw = dict(k=8, window=window, chunk=chunk, plan=plan,
+              num_devices=(1, 1) if plan == "hybrid" else None)
+    got = knn_query_batch_chunked(idx, qpos, qid, **kw)
+    jax.clear_caches()
+    monkeypatch.setattr(pipeline, "window_fetch", _element_gather_fetch)
+    want = knn_query_batch_chunked(idx, qpos, qid, **kw)
+    monkeypatch.undo()
+    jax.clear_caches()
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    for field in ("iterations", "candidates", "leaves_visited"):
+        assert getattr(got[2], field) == getattr(want[2], field), field
+    assert 0 < got[2].windows_fetched <= got[2].iterations * chunk
+
+
+def test_windows_fetched_counts_every_lane_when_all_scan():
+    """One leaf holds every object: each lane scans it on every trip, so
+    every lane fetches a window on every trip."""
+    rng = np.random.default_rng(3)
+    pts = rng.uniform(0, 1000, (600, 2)).astype(np.float32)
+    idx = build_index(jnp.asarray(pts), jnp.zeros(2), 1000.0, l_max=3,
+                      th_quad=1000)
+    qid = np.arange(600, dtype=np.int32)
+    _, _, stats = knn_query_batch_chunked(idx, pts, qid, k=4, window=128,
+                                          chunk=256)
+    assert stats.iterations == 3 * -(-600 // 128)  # three chunks, 5 trips each
+    assert stats.windows_fetched == stats.iterations * 256

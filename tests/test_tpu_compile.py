@@ -6,9 +6,10 @@ topology described by ``jax.experimental.topologies`` (no chip needed), at
 the widths the service uses (query chunk 8192, window 256, k 32), so a
 kernel the TPU's compiler (Mosaic) refuses fails here; each kernel must
 show in the compiled program under its stable name, the instruction name a
-profiler trace shows it by.  The last test
-compiles a whole ``fused_bucket`` tick for that chip and requires the
-kernel in it: a program lowered for the TPU must carry it compiled
+profiler trace shows it by.  The last tests
+compile whole ticks for that chip and require their kernels in them
+(``fused_scan`` under ``fused_bucket``, ``window_fetch`` under every
+backend): a program lowered for the TPU must carry them compiled
 (``tpu_custom_call``), never interpreted.
 """
 import re
@@ -26,6 +27,7 @@ from repro.kernels import (
     merge_topk,
     pairwise_dist,
     topk_select,
+    window_fetch,
 )
 
 Q, W, K = 8192, 256, 32
@@ -107,19 +109,30 @@ def test_other_kernels_compile_for_tpu_under_their_names(tpu, name, fn,
     assert _compiled_kernel(_compile_text(fn, tpu, *shapes), name)
 
 
-def test_fused_bucket_tick_carries_the_compiled_kernel(tpu, monkeypatch):
-    """A whole tick, lowered for the chip with the kernels left to the
-    default (``interpret=None``): the kernel is compiled into it, while the
-    same session's CPU program interprets it."""
+def test_window_fetch_compiles_for_tpu(tpu):
+    n = 1_000_000  # the row tables of a million-object store
+    rows = (n - 1) // window_fetch.LANES + window_fetch.fetch_rows(W)
+    text = _compile_text(
+        lambda x, y, ids, start, scanning: window_fetch.window_fetch(
+            (x, y, ids), start, scanning, window=W, interpret=False),
+        tpu, ((rows, 128), F32), ((rows, 128), F32), ((rows, 128), I32),
+        ((Q,), I32), ((Q,), jnp.bool_),
+    )
+    assert _compiled_kernel(text, "window_fetch")
+
+
+def _tick_on_tpu(tpu, monkeypatch, backend):
+    """A small session's tick, compiled on the CPU and for the described
+    chip: ``(cpu_text, tpu_text)`` of the two compiled programs."""
     n = 2048
     rng = np.random.default_rng(0)
     pts = rng.uniform(0, 1000, (n, 2)).astype(np.float32)
     sess = KnnSession(ServiceSpec(k=8, th_quad=64, l_max=5, window=64,
-                                  chunk=512, backend="fused_bucket"))
+                                  chunk=512, backend=backend))
     sess.ingest_objects(pts)
     sess.register_queries(pts, np.arange(n, dtype=np.int32))
     sess.submit().result()
-    assert "tpu_custom_call" not in sess.lower_tick().compile().as_text()
+    cpu_text = sess.lower_tick().compile().as_text()
 
     # the same arguments, as shapes on the described chip
     step = session_mod._tick_step
@@ -133,4 +146,21 @@ def test_fused_bucket_tick_carries_the_compiled_kernel(tpu, monkeypatch):
             return step.lower(*args, **statics)
 
     monkeypatch.setattr(session_mod, "_tick_step", OnTpu())
-    assert "tpu_custom_call" in sess.lower_tick().compile().as_text()
+    return cpu_text, sess.lower_tick().compile().as_text()
+
+
+def test_dense_topk_tick_carries_the_compiled_window_fetch(tpu, monkeypatch):
+    """The default backend's tick carries the window fetch compiled, under
+    its stable name, and interprets it on the CPU."""
+    cpu_text, tpu_text = _tick_on_tpu(tpu, monkeypatch, "dense_topk")
+    assert "tpu_custom_call" not in cpu_text
+    assert _compiled_kernel(tpu_text, "window_fetch")
+
+
+def test_fused_bucket_tick_carries_the_compiled_kernel(tpu, monkeypatch):
+    """A whole tick, lowered for the chip with the kernels left to the
+    default (``interpret=None``): the kernel is compiled into it, while the
+    same session's CPU program interprets it."""
+    cpu_text, tpu_text = _tick_on_tpu(tpu, monkeypatch, "fused_bucket")
+    assert "tpu_custom_call" not in cpu_text
+    assert _compiled_kernel(tpu_text, "fused_scan")
