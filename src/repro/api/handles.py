@@ -78,6 +78,7 @@ class TickHandle:
         collect: str = "full",
         agg=None,
         maintenance: str = "rebuild",
+        delta_rows: int = 0,
     ):
         self._session = session
         self.tick = tick
@@ -100,6 +101,7 @@ class TickHandle:
         # "incremental" | "skip") — the session's scheduling decision,
         # recorded for TickResult.maintenance
         self._maintenance = maintenance
+        self._delta_rows = delta_rows
         # set by the session at finalize time
         self._finalized = False
         self._rebuilt_post = False
@@ -172,6 +174,7 @@ class TickHandle:
             collect_s=collect_s,
             aggregates=aggregates,
             maintenance=self._maintenance,
+            delta_rows=self._delta_rows,
         )
 
     def result(self, materialize: bool = True) -> TickResult:

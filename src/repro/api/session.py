@@ -253,6 +253,9 @@ class KnnSession:
         self._pending_old_batches: list = []
         self._pending_old_rows = 0
         self._pending_src: np.ndarray | None = None
+        # (mode, padded delta length) of the last tick that refreshed the
+        # index in its step: the program lower_tick() lowers
+        self._last_refresh: tuple[str, int] = ("rebuild", 0)
 
     # ------------------------------------------------------------ state views
     @property
@@ -699,45 +702,15 @@ class KnnSession:
                 )
             qweight_dev = self._qweight_staged[2]
         spec = self.spec
-        # --- maintenance decision (DESIGN.md §15), made per tick, host-side:
-        # clean buffer -> "skip" (reindex would be a bitwise no-op);
-        # known small delta under an incremental spec -> "incremental";
-        # anything else (rebuild spec, snapshot ingest, churn over budget)
-        # -> full "rebuild" refresh.  Each mode is a static of the step, so
-        # every (shape, mode) pair is its own cached executable.
-        n = self.num_objects
-        delta_ids_dev = None
-        delta_old_pos_dev = None
-        if not self._positions_dirty:
-            mode = "skip"
-        elif (
-            spec.maintenance == "incremental"
-            and self._pending_ids is not None
-            and self._pending_ids.size <= spec.churn_budget * n
-        ):
-            mode = "incremental"
-            # as-of-refresh positions of the pending ids: one gather over
-            # the captured pre-scatter batches (device-side, async)
-            delta_ids_dev, delta_old_pos_dev = self._assemble_delta()
-            if self.plan.object_axis_size > 1:
-                # per-shard budget (DESIGN.md §15): the global fraction can
-                # hide one shard absorbing most of the churn — past
-                # churn_budget × its OWNED rows, that shard's local re-sort
-                # is the cheaper refresh, so the whole tick defers.  One ()
-                # bool readback against the last tick's index/boundaries; the
-                # pending ticks were already finalized above, so this is not
-                # a new synchronization point.
-                if bool(shard_churn_over_budget(
-                    self._index, delta_ids_dev, self.plan.object_axis_size,
-                    spec.churn_budget, self._obj_bounds,
-                )):
-                    mode = "rebuild"
-                    delta_ids_dev = delta_old_pos_dev = None
-        else:
-            # over-budget churn defers to the FULL stage-(ii) refresh (not
-            # build_index: the z_map stays put so the drift trigger fires
-            # identically under both maintenance policies)
-            mode = "rebuild"
+        with span("session.delta", tick=self._tick):
+            mode, delta_ids_dev, delta_old_pos_dev = self._maintenance_step()
+        # rows the refresh re-places (TickResult.delta_rows)
+        delta_rows = 0
+        if mode != "skip":
+            delta_rows = (self.num_objects if delta_ids_dev is None
+                          else int(self._pending_ids.size))
+            self._last_refresh = (
+                mode, 0 if delta_ids_dev is None else delta_ids_dev.shape[0])
         self._index, nn_idx, nn_dist, aux, should_rebuild = _tick_step(
             self._index,
             self._positions,
@@ -797,7 +770,52 @@ class KnnSession:
             collect=spec.collect,
             agg=agg,
             maintenance=mode,
+            delta_rows=delta_rows,
         )
+
+    def _maintenance_step(self):
+        """``(mode, delta_ids, delta_old_pos)`` of the next tick's refresh."""
+        spec = self.spec
+        # --- maintenance decision (DESIGN.md §15), made per tick, host-side:
+        # clean buffer -> "skip" (reindex would be a bitwise no-op);
+        # known small delta under an incremental spec -> "incremental";
+        # anything else (rebuild spec, snapshot ingest, churn over budget)
+        # -> full "rebuild" refresh.  Each mode is a static of the step, so
+        # every (shape, mode) pair is its own cached executable.
+        n = self.num_objects
+        delta_ids_dev = None
+        delta_old_pos_dev = None
+        if not self._positions_dirty:
+            mode = "skip"
+        elif (
+            spec.maintenance == "incremental"
+            and self._pending_ids is not None
+            and self._pending_ids.size <= spec.churn_budget * n
+        ):
+            mode = "incremental"
+            # as-of-refresh positions of the pending ids: one gather over
+            # the captured pre-scatter batches (device-side, async)
+            delta_ids_dev, delta_old_pos_dev = self._assemble_delta()
+            if self.plan.object_axis_size > 1:
+                # per-shard budget (DESIGN.md §15): the global fraction can
+                # hide one shard absorbing most of the churn — past
+                # churn_budget × its OWNED rows, that shard's local re-sort
+                # is the cheaper refresh, so the whole tick defers.  One ()
+                # bool readback against the last tick's index/boundaries; the
+                # pending ticks were already finalized above, so this is not
+                # a new synchronization point.
+                if bool(shard_churn_over_budget(
+                    self._index, delta_ids_dev, self.plan.object_axis_size,
+                    spec.churn_budget, self._obj_bounds,
+                )):
+                    mode = "rebuild"
+                    delta_ids_dev = delta_old_pos_dev = None
+        else:
+            # over-budget churn defers to the FULL stage-(ii) refresh (not
+            # build_index: the z_map stays put so the drift trigger fires
+            # identically under both maintenance policies)
+            mode = "rebuild"
+        return mode, delta_ids_dev, delta_old_pos_dev
 
     def _step_statics(self, mode: str) -> dict:
         """The static arguments of :func:`_tick_step` under this spec."""
@@ -811,16 +829,23 @@ class KnnSession:
     def lower_tick(self):
         """The tick program for the current state, lowered but not run.
 
-        Returns the ``jax.stages.Lowered`` of the full-refresh (``"rebuild"``)
-        step over the live index, object buffer and query registry; its
-        ``compile()`` gives the executable's text (which kernels it runs: a
-        Pallas kernel compiled for the TPU shows as ``tpu_custom_call``) and
-        memory analysis.  Needs a submitted tick (the index is built lazily
-        at the first ``submit()``).
+        Returns the ``jax.stages.Lowered`` of the step that last refreshed
+        the index (``"incremental"``, at the padded length of its delta, or
+        the full-refresh ``"rebuild"``, which is also what a session whose
+        every tick skipped gets) over the live index, object buffer and
+        query registry; its ``compile()`` gives the executable's text (which
+        kernels it runs: a Pallas kernel compiled for the TPU shows as
+        ``tpu_custom_call``) and memory analysis.  Needs a submitted tick
+        (the index is built lazily at the first ``submit()``).
         """
         if self._index is None:
             raise RuntimeError("lower_tick before the first submit: no index")
         qpos_dev, qid_dev = self._registry.staged()[:2]
+        mode, m = self._last_refresh
+        delta_ids = delta_old_pos = None
+        if mode == "incremental":
+            delta_ids = jnp.full((m,), self.num_objects, jnp.int32)
+            delta_old_pos = jnp.zeros((m, 2), jnp.float32)
         return _tick_step.lower(
             self._index,
             self._positions,
@@ -829,10 +854,10 @@ class KnnSession:
             jnp.zeros((qpos_dev.shape[0],), jnp.float32),
             jnp.float32(np.inf),
             jnp.float32(self.spec.rebuild_factor),
+            delta_ids,
+            delta_old_pos,
             None,
-            None,
-            None,
-            **self._step_statics("rebuild"),
+            **self._step_statics(mode),
         )
 
     def process_tick(self, positions, qpos, qid=None):

@@ -549,7 +549,13 @@ def reindex_objects_delta(
     src_a, b_src = sparse_splice_plan(slots, res[p:], n)
     codes_n = gather_splice(src_a, b_src, index.codes, codes_b)
     ids_n = gather_splice(src_a, b_src, index.ids, ids_b)
-    pos_n = gather_splice(src_a, b_src, index.pos, pos_b)
+    # one coordinate at a time: on the TPU a select between two gathers of
+    # (N, 2) rows is laid out with its 2-wide minor dimension padded to 128
+    # lanes (1 GB of temporaries at N = 1M on a v5e; 20 MB per coordinate)
+    pos_n = jnp.stack([
+        gather_splice(src_a, b_src, index.pos[:, d], pos_b[:, d])
+        for d in range(2)
+    ], axis=1)
     pyramid = pyramid_delta(
         index.pyramid,
         old_codes,

@@ -250,6 +250,10 @@ class TickResult:
     # refresh), "incremental" (delta splice), or "skip" (dirty-flag fast
     # path: nothing moved since the last refresh, reindex elided)
     maintenance: str = "rebuild"
+    # rows the tick's index refresh re-placed: the pending delta's unique
+    # ids under "incremental", 0 under "skip", N under "rebuild" (the
+    # whole re-sort); known on the host at dispatch, no readback
+    delta_rows: int = 0
 
     @property
     def kth_dist(self):

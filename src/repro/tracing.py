@@ -8,8 +8,9 @@ cost next to nothing when no session records.
   carrying ``tick=<τ>`` so the spans of one tick share an identifier.  The
   session layer marks its boundaries and the points where the host blocks:
   ``session.ingest``, ``session.update_queries``, ``session.submit`` (with
-  ``session.finalize``, ``session.rebuild`` and ``session.dispatch``),
-  ``tick.wait``, ``tick.result`` (with ``tick.collect``).
+  ``session.finalize``, ``session.rebuild`` and ``session.dispatch``, which
+  holds ``session.delta``: the maintenance decision and the delta's
+  assembly), ``tick.wait``, ``tick.result`` (with ``tick.collect``).
 * :func:`stage` — a ``jax.named_scope`` named ``knn.<stage>`` for one stage
   of the tick program (:data:`STAGES`).  It is trace-time metadata: the
   compiled ops carry it in their ``op_name``, and no device work is added.

@@ -556,7 +556,8 @@ def test_one_tick_emits_the_session_spans(tmp_path):
     assert set(spans) == {
         "knn.session.ingest", "knn.session.update_queries",
         "knn.session.submit", "knn.session.finalize", "knn.session.dispatch",
-        "knn.tick.wait", "knn.tick.result", "knn.tick.collect",
+        "knn.session.delta", "knn.tick.wait", "knn.tick.result",
+        "knn.tick.collect",
     }
     assert [t for t, _, _ in spans["knn.session.submit"]] == [1]
     assert [t for t, _, _ in spans["knn.session.finalize"]] == [0]
@@ -569,7 +570,35 @@ def test_one_tick_emits_the_session_spans(tmp_path):
 
     assert inside("knn.session.finalize", "knn.session.submit")
     assert inside("knn.session.dispatch", "knn.session.submit")
+    assert inside("knn.session.delta", "knn.session.dispatch")
     assert inside("knn.tick.collect", "knn.tick.result")
+
+
+def test_a_delta_tick_emits_the_delta_span(tmp_path):
+    """A tick fed by ``update_objects`` under the incremental spec marks its
+    maintenance decision and delta assembly as ``knn.session.delta``, inside
+    the dispatch, and reports the rows it spliced."""
+    rng = np.random.default_rng(4)
+    pts = rng.uniform(0, 22_500, (500, 2)).astype(np.float32)
+    sess = KnnSession(_spec(maintenance="incremental"))
+    sess.ingest_objects(pts)
+    sess.register_queries(pts[:40], np.arange(40))
+    sess.submit().result()
+    ids = rng.choice(500, 30, replace=False).astype(np.int32)
+    sess.update_objects(ids, rng.uniform(0, 22_500, (30, 2)).astype(
+        np.float32))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        res = sess.submit().result()
+    finally:
+        jax.profiler.stop_trace()
+    assert res.maintenance == "incremental" and res.delta_rows == 30
+    spans = _trace_spans(tmp_path)
+    (tick, s, e), = spans["knn.session.delta"]
+    (_, ds, de), = spans["knn.session.dispatch"]
+    assert tick == 1 and ds <= s and e <= de
 
 
 # ------------------------------------------------------- drift rebuild

@@ -30,6 +30,7 @@ from repro.core import (
     EngineConfig,
     MAINTENANCE_MODES,
     build_index,
+    knn_bruteforce,
     pyramid_delta,
     rebuild_zmap,
     reindex_objects,
@@ -322,9 +323,71 @@ def test_session_modes_and_bit_identity():
         ra, rb = a.submit().result(), b.submit().result()
         assert ra.maintenance == want_a[t], t
         assert rb.maintenance == want_b[t], t
+        # rows each refresh re-placed: the whole store on a re-sort, the
+        # moved rows on a splice, none on a skip
+        rows = {"skip": 0, "rebuild": n, "incremental": mv}
+        assert ra.delta_rows == rows[ra.maintenance], t
+        assert rb.delta_rows == rows[rb.maintenance], t
         np.testing.assert_array_equal(ra.nn_idx, rb.nn_idx, err_msg=str(t))
         np.testing.assert_array_equal(ra.nn_dist, rb.nn_dist, err_msg=str(t))
         _assert_index_equal(a.index, b.index)
+
+
+def test_cyclic_reports_one_tick_ahead_match_brute_force():
+    """The delta-reporting deployment in small: each tick the next ``r``
+    objects of one fixed cyclic order report their fix (``update_objects``),
+    one tick stays in flight, and each standing query follows its issuer's
+    last report.  Every tick matches the brute force over the host mirror
+    of the reported world, and a ``maintenance="rebuild"`` session fed the
+    same stream bitwise; ``delta_rows`` counts the tick's reports."""
+    rng = np.random.default_rng(21)
+    n, r, q, k, ticks = 600, 60, 48, 4, 14  # the order wraps after 10 ticks
+    mirror = rng.uniform(0, SIDE, (n, 2)).astype(np.float32)
+    order = rng.permutation(n).astype(np.int32)
+    qid = rng.choice(n, q, replace=False).astype(np.int32)
+    sessions, handles = [], []
+    for maintenance in ("incremental", "rebuild"):
+        s = KnnSession(ServiceSpec(
+            k=k, chunk=256, window=32, l_max=5, th_quad=32, side=SIDE,
+            delta_pad=64, maintenance=maintenance))
+        # a copy: on the CPU the session's buffer may alias what it is
+        # handed, and the mirror is written below
+        s.ingest_objects(mirror.copy())
+        handles.append(s.register_queries(mirror[qid], qid))
+        sessions.append(s)
+    prev = [s.submit() for s in sessions]
+    world = mirror.copy()
+    modes = []
+
+    def check(pending, world):
+        inc, full = (h.result() for h in pending)
+        bi, bd = knn_bruteforce(jnp.asarray(world), jnp.asarray(world[qid]),
+                                jnp.asarray(qid), k)
+        np.testing.assert_array_equal(inc.nn_idx, np.asarray(bi))
+        np.testing.assert_allclose(inc.nn_dist, np.asarray(bd), rtol=1e-6)
+        np.testing.assert_array_equal(inc.nn_idx, full.nn_idx)
+        np.testing.assert_array_equal(inc.nn_dist, full.nn_dist)
+        rows = {"skip": 0, "incremental": r, "rebuild": n}
+        assert inc.delta_rows == rows[inc.maintenance]
+        assert full.delta_rows == rows[full.maintenance]
+        modes.append(inc.maintenance)
+
+    for t in range(ticks):
+        ids = order[(t * r + np.arange(r)) % n]
+        fix = np.clip(mirror[ids] + rng.normal(0, 25, (r, 2)), 0,
+                      SIDE - 1e-3).astype(np.float32)
+        for s in sessions:
+            s.update_objects(ids, fix)
+        mirror[ids] = fix
+        for h in prev:
+            h.block_until_ready()
+        for s, hq in zip(sessions, handles):
+            s.update_queries(hq, mirror[qid])
+        nxt = [s.submit() for s in sessions]
+        check(prev, world)
+        prev, world = nxt, mirror.copy()
+    check(prev, world)
+    assert modes[0] == "skip" and modes.count("incremental") >= ticks - 2
 
 
 def test_session_snapshot_ingest_forces_full_refresh():

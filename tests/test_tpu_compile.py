@@ -10,7 +10,8 @@ profiler trace shows it by.  The last tests
 compile whole ticks for that chip and require their kernels in them
 (``fused_scan`` under ``fused_bucket``, ``window_fetch`` under every
 backend): a program lowered for the TPU must carry them compiled
-(``tpu_custom_call``), never interpreted.
+(``tpu_custom_call``), never interpreted; one of them is the incremental
+tick at the delta-reporting cell's full shapes.
 """
 import re
 
@@ -21,6 +22,7 @@ import pytest
 
 from repro.api import KnnSession, ServiceSpec
 from repro.api import session as session_mod
+from repro.core import build_index
 from repro.kernels import (
     bucket_kselect,
     fused_scan,
@@ -164,3 +166,33 @@ def test_fused_bucket_tick_carries_the_compiled_kernel(tpu, monkeypatch):
     cpu_text, tpu_text = _tick_on_tpu(tpu, monkeypatch, "fused_bucket")
     assert "tpu_custom_call" not in cpu_text
     assert _compiled_kernel(tpu_text, "fused_scan")
+
+
+def test_incremental_tick_compiles_for_tpu_at_the_churn_cell_shapes(tpu):
+    """The tick the delta-reporting cell runs: the incremental splice step
+    at N 1,000,000, a padded delta of 32,768 rows, Q 16,384, W 256, chunk
+    8,192, compiled for the described chip with the window fetch in it.
+    At this N the splice searches int32 key pairs, since
+    ``4**l_max * (N + 1) + N`` does not fit an int32."""
+    n, delta, q = 1_000_000, 32_768, 16_384
+    spec = ServiceSpec(maintenance="incremental")
+    on = jax.sharding.SingleDeviceSharding(tpu)
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=on)
+
+    index = jax.eval_shape(
+        lambda p: build_index(p, jnp.zeros(2, F32), spec.side,
+                              l_max=spec.l_max, th_quad=spec.th_quad),
+        jax.ShapeDtypeStruct((n, 2), F32))
+    index = jax.tree.map(lambda x: shape(x.shape, x.dtype), index)
+    args = (index, shape((n, 2), F32), shape((q, 2), F32), shape((q,), I32),
+            shape((q,), F32), shape((), F32), shape((), F32),
+            shape((delta,), I32), shape((delta, 2), F32), None)
+    statics = KnnSession(spec)._step_statics("incremental")
+    compiled = session_mod._tick_step.lower(*args, **statics).compile()
+    assert _compiled_kernel(compiled.as_text(), "window_fetch")
+    # a select between two gathers of (N, 2) positions takes a layout
+    # padded to 128 lanes, 1,033 MB of temporaries; one coordinate at a
+    # time the step needs 74 MB, against the full re-sort's 61 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 150e6
