@@ -15,7 +15,8 @@ tasks on the fly and sorts them by weight to balance GPU SMs.  Under XLA we run 
     top-k merge), or
   * NAVigates the *virtual full quadtree* (arithmetic-only, paper Sec. 4.2.2):
     up to ``max_nav`` aligned-block jumps that skip empty (count-pyramid) or
-    pruned (box farther than kth) regions in O(4^a)-sized strides.
+    pruned (box farther than kth) regions in O(4^a)-sized strides; each step
+    reads one packed navigation word per lane (:func:`nav_table`).
 Queries are pre-sorted by Morton code, so active lanes stay spatially coherent —
 the same locality argument as the paper's SM-task packing, expressed as vector-lane
 coherence instead of warp coherence.
@@ -73,6 +74,7 @@ class KnnStats(NamedTuple):
     candidates: jnp.ndarray  # () i64-ish f32 — total candidate object slots scanned
     leaves_visited: jnp.ndarray  # () i32 — scheduled leaf scans (incl. own leaf)
     windows_fetched: jnp.ndarray  # () i32 — lane windows fetched, over trips
+    nav_lane_steps: jnp.ndarray  # () i32 — NAV lane steps that ran, over trips
 
 
 def zero_stats() -> KnnStats:
@@ -82,6 +84,7 @@ def zero_stats() -> KnnStats:
         candidates=jnp.float32(0.0),
         leaves_visited=jnp.int32(0),
         windows_fetched=jnp.int32(0),
+        nav_lane_steps=jnp.int32(0),
     )
 
 
@@ -101,20 +104,94 @@ class _State(NamedTuple):
     cand_q: jnp.ndarray  # (Q,) f32 — candidate slots scanned PER QUERY (cost model)
     leaves: jnp.ndarray  # () i32
     fetched: jnp.ndarray  # () i32 — lanes that fetched a window, over trips
+    nav_steps: jnp.ndarray  # () i32 — NAV lane steps that ran, over trips
 
 
-def _nav_step(index: QuadtreeIndex, qx, qy, kth2, cursor, run, dir_r):
+# A navigation word (:func:`nav_table`) packs every read of one NAV step:
+# bits 0-4 the probed leaf's level, bit 5 whether that leaf holds objects,
+# and bit ``_EMPTY_BIT0 + a - 1`` whether the block of ``4**a`` fine cells
+# the step would skip from an aligned cursor is empty, for a = 1..l_max.
+_LVL_MASK = 31
+_CNT_BIT = 5
+_EMPTY_BIT0 = 6
+
+
+def _shift(x, m, fill):
+    """``y[p] = x[p + m]`` (static ``m``), ``fill`` where ``p + m`` leaves ``x``."""
+    return jax.lax.pad(x, fill.astype(x.dtype), [(-m, m, 0)])
+
+
+def nav_table(index: QuadtreeIndex) -> jnp.ndarray:
+    """The NAV step's reads for every probed cell, one int32 word each way.
+
+    ``table[dir_r * 4**l_max + cprobe]``, where ``cprobe`` is the fine cell
+    a step probes: the cursor's own (rightwards) or the one before it
+    (leftwards), clipped into the domain.  What a step reads from
+    ``leaf_level``, ``starts`` and the count pyramid depends on that cell
+    and the direction only, never on the query, so the walk reads one word
+    per lane and step.  Built once for every chunk swept over the index.
+
+    A block's population is a difference of ``starts``, so the empty tests
+    compare ``starts`` with itself at static shifts: rightwards the cells
+    ``[p, p + 4**a)``, leftwards ``(p - 4**a, p]``.  From an aligned cursor
+    these are the aligned blocks the count pyramid holds, and only an
+    aligned cursor reads them.  The leftward leaf count reads
+    ``starts`` at the leaf's bounds.  No table of a 4**l_max-cell layout is
+    repeated or interleaved: on a TPU such relayouts compile to megabytes
+    of code in every program that sweeps.
+    """
+    l_max = index.l_max
+    if _EMPTY_BIT0 + l_max > 31:
+        raise ValueError(f"l_max {l_max} does not fit a navigation word")
+    n_fine = 4**l_max
+    ll, starts = index.leaf_level, index.starts
+    a0 = (l_max - ll).astype(jnp.int32)
+    with stage("nav"):
+        left, right = ll, ll
+        found_r = jnp.zeros(n_fine, bool)
+        for a in range(l_max + 1):
+            empty_r = (_shift(starts, 4**a, starts[n_fine])[:n_fine]
+                       == starts[:n_fine])
+            empty_l = (_shift(starts, 1 - 4**a, starts[0])[:n_fine]
+                       == starts[1:])
+            # rightwards the leaf is [cprobe, cprobe + span0), clipped
+            found_r = jnp.where(a0 == a, ~empty_r, found_r)
+            if a:
+                right = right | (empty_r.astype(jnp.int32)
+                                 << (_EMPTY_BIT0 + a - 1))
+                left = left | (empty_l.astype(jnp.int32)
+                               << (_EMPTY_BIT0 + a - 1))
+        # leftwards it is the aligned block holding cprobe
+        cprobe = jnp.arange(n_fine, dtype=jnp.int32)
+        span0 = jnp.left_shift(jnp.int32(1), 2 * a0)
+        s, e = _leaf_offsets(starts, (cprobe >> (2 * a0)) << (2 * a0), span0)
+        left = left | ((e > s).astype(jnp.int32) << _CNT_BIT)
+        right = right | (found_r.astype(jnp.int32) << _CNT_BIT)
+        return jnp.stack([left, right]).reshape(-1)
+
+
+def _leaf_offsets(starts, leaf_key, span0):
+    """Object interval ``[s, e)`` of the leaf ``[leaf_key, leaf_key + span0)``."""
+    n_fine = starts.shape[0] - 1
+    return (starts[jnp.clip(leaf_key, 0, n_fine - 1)],
+            starts[jnp.clip(leaf_key + span0, 0, n_fine)])
+
+
+def _nav_step(index: QuadtreeIndex, table, qx, qy, kth2, cursor, run, dir_r):
     """One navigation step; ``dir_r`` is a per-query bool (True = rightwards).
 
-    Returns (found, s, e, new_cursor, exhausted):
+    Returns (found, leaf_key, span0, new_cursor, exhausted):
       found     — a near, non-empty leaf was located (schedule its scan)
-      s, e      — object interval of that leaf
+      leaf_key, span0 — that leaf's fine cells ``[leaf_key, leaf_key + span0)``
+                  (:func:`_leaf_offsets` gives its object interval)
       new_cursor— cursor after the step (past the found leaf, or past the skipped
                   aligned block)
       exhausted — cursor left the domain; direction goes inactive
 
-    All loops are rolled (lax.fori_loop) to keep the compiled program small; the
-    pyramid is indexed at a *dynamic* level via its flat layout.
+    The step reads one word of ``table`` (:func:`nav_table`) per lane; the
+    rest is arithmetic.  The level loop is unrolled: with no memory read
+    left in it, its ``l_max`` distance and bit tests fuse into one
+    elementwise pass.
     """
     l_max = index.l_max
     n_fine = 4**l_max
@@ -122,15 +199,13 @@ def _nav_step(index: QuadtreeIndex, qx, qy, kth2, cursor, run, dir_r):
 
     exhausted = jnp.where(dir_r, cursor >= n_fine, cursor <= 0)
     cprobe = jnp.clip(jnp.where(dir_r, cursor, cursor - 1), 0, n_fine - 1)
+    word = table[dir_r.astype(jnp.int32) * n_fine + cprobe]
 
-    lvl = index.leaf_level[cprobe]
+    lvl = word & _LVL_MASK
     a0 = (l_max - lvl).astype(jnp.int32)
     span0 = jnp.left_shift(one, 2 * a0)
     # leaf start (right: == cursor; left: aligned block ending at cursor)
     leaf_key = jnp.where(dir_r, cprobe, (cprobe >> (2 * a0)) << (2 * a0))
-    s = index.starts[jnp.clip(leaf_key, 0, n_fine - 1)]
-    e = index.starts[jnp.clip(leaf_key + span0, 0, n_fine)]
-    cnt = e - s
     leaf_d2 = morton.point_to_block_dist2(
         qx, qy, leaf_key, a0, index.origin, index.side, l_max
     )
@@ -139,19 +214,17 @@ def _nav_step(index: QuadtreeIndex, qx, qy, kth2, cursor, run, dir_r):
     # Together with the lexicographic (d2, id) selection contract (DESIGN.md
     # §12) this makes the result a pure function of the candidate set —
     # identical bits under any chunking, query sharding or object partition.
-    found = run & ~exhausted & (cnt > 0) & (leaf_d2 <= kth2)
+    nonempty = ((word >> _CNT_BIT) & 1) == 1
+    found = run & ~exhausted & nonempty & (leaf_d2 <= kth2)
 
     # --- far/empty aligned-block skip: pick the largest admissible jump.
-    pyr_n = index.pyramid.shape[0]
-
-    def try_level(a, best_a):
+    best_a = a0
+    for a in range(1, l_max + 1):
         ai = jnp.int32(a)
-        blk = jnp.left_shift(one, 2 * ai)
+        blk = 4**a
         code = jnp.where(dir_r, cursor, cursor - blk)
         in_dom = jnp.where(dir_r, cursor + blk <= n_fine, cursor - blk >= 0)
-        pidx = jnp.where(dir_r, cursor >> (2 * ai), (cursor >> (2 * ai)) - 1)
-        lvl_off = (jnp.left_shift(one, 2 * (l_max - ai)) - 1) // 3
-        empty = index.pyramid[jnp.clip(lvl_off + pidx, 0, pyr_n - 1)] == 0
+        empty = ((word >> (_EMPTY_BIT0 + a - 1)) & 1) == 1
         far = (
             morton.point_to_block_dist2(
                 qx, qy, code, ai, index.origin, index.side, l_max
@@ -160,16 +233,14 @@ def _nav_step(index: QuadtreeIndex, qx, qy, kth2, cursor, run, dir_r):
         )
         aligned = (cursor & (blk - 1)) == 0
         ok = aligned & in_dom & (ai >= a0) & (empty | far)
-        return jnp.where(ok & (ai > best_a), ai, best_a)
-
-    best_a = jax.lax.fori_loop(1, l_max + 1, try_level, a0)
+        best_a = jnp.where(ok & (ai > best_a), ai, best_a)
     jump = jnp.left_shift(one, 2 * best_a)
 
     step = jnp.where(found, span0, jump)
     new_cursor = jnp.where(
         run & ~exhausted, jnp.where(dir_r, cursor + step, cursor - step), cursor
     )
-    return found, s, e, new_cursor, run & exhausted
+    return found, leaf_key, span0, new_cursor, run & exhausted
 
 
 def _knn_sorted_impl(
@@ -182,11 +253,13 @@ def _knn_sorted_impl(
     max_iters: int,
     executor: QueryExecutor,
     tables,
+    nav_words,
 ):
     """k-NN for queries already sorted by Morton code (trace-level body).
 
     ``tables`` are the index's row tables for ``window``
-    (:func:`window_tables`), built once for every chunk swept over it.
+    (:func:`window_tables`) and ``nav_words`` its navigation words
+    (:func:`nav_table`), both built once for every chunk swept over it.
     """
     nq = qpos.shape[0]
     n_fine = index.n_fine
@@ -218,6 +291,7 @@ def _knn_sorted_impl(
         cand_q=jnp.zeros((nq,), jnp.float32),
         leaves=(e0 > s0).sum().astype(jnp.int32),
         fetched=jnp.int32(0),
+        nav_steps=jnp.int32(0),
     )
 
     def live(st: _State):
@@ -265,39 +339,39 @@ def _knn_sorted_impl(
         nav = ~scanning & (st.act_l | st.act_r)
 
         def nav_body(_, nst):
-            cl, cr, act_l, act_r, next_right, s_cur, e_cur, found_any = nst
+            (cl, cr, act_l, act_r, next_right, f_key, f_span, found_any,
+             steps) = nst
             pending = nav & ~found_any & (act_l | act_r)
             go_right = act_r & (next_right | ~act_l)
             run = pending & (go_right | act_l)
             cursor = jnp.where(go_right, cr, cl)
-            f, s_f, e_f, cur2, ex = _nav_step(
-                index, qx, qy, kth2, cursor, run, go_right
+            f, key_f, span_f, cur2, ex = _nav_step(
+                index, nav_words, qx, qy, kth2, cursor, run, go_right
             )
             cr = jnp.where(run & go_right, cur2, cr)
             cl = jnp.where(run & ~go_right, cur2, cl)
             act_r = act_r & ~(ex & go_right)
             act_l = act_l & ~(ex & ~go_right)
-            s_cur = jnp.where(f, s_f, s_cur)
-            e_cur = jnp.where(f, e_f, e_cur)
+            # a lane finds at most one leaf a trip (`pending` drops it after)
+            f_key = jnp.where(f, key_f, f_key)
+            f_span = jnp.where(f, span_f, f_span)
             # alternate directions while both remain active (paper Sec. 4.2.2)
             next_right = jnp.where(f, ~go_right, next_right)
             found_any = found_any | f
-            return cl, cr, act_l, act_r, next_right, s_cur, e_cur, found_any
+            steps = steps + run.astype(jnp.int32)
+            return (cl, cr, act_l, act_r, next_right, f_key, f_span,
+                    found_any, steps)
 
-        nst = (
-            st.cl,
-            st.cr,
-            st.act_l,
-            st.act_r,
-            st.next_right,
-            st.s_cur,
-            st.e_cur,
-            jnp.zeros((nq,), bool),
-        )
+        zeros = jnp.zeros((nq,), jnp.int32)
+        nst = (st.cl, st.cr, st.act_l, st.act_r, st.next_right, zeros, zeros,
+               jnp.zeros((nq,), bool), zeros)
         with stage("nav"):
-            cl, cr, act_l, act_r, next_right, s_cur, e_cur, found_any = (
-                jax.lax.fori_loop(0, max_nav, nav_body, nst)
-            )
+            (cl, cr, act_l, act_r, next_right, f_key, f_span, found_any,
+             steps) = jax.lax.fori_loop(0, max_nav, nav_body, nst)
+            # the found leaf's object interval: two reads a trip, not a step
+            s_f, e_f = _leaf_offsets(index.starts, f_key, f_span)
+            s_cur = jnp.where(found_any, s_f, st.s_cur)
+            e_cur = jnp.where(found_any, e_f, st.e_cur)
 
         scanning = scanning | found_any
         off = jnp.where(found_any, 0, off)
@@ -319,6 +393,7 @@ def _knn_sorted_impl(
             cand_q=cand_q,
             leaves=leaves,
             fetched=st.fetched + st.scanning.sum().astype(jnp.int32),
+            nav_steps=st.nav_steps + steps.sum(),
         )
 
     st = jax.lax.while_loop(cond, body, state)
@@ -327,6 +402,7 @@ def _knn_sorted_impl(
         candidates=st.cand_q.sum(),
         leaves_visited=st.leaves,
         windows_fetched=st.fetched,
+        nav_lane_steps=st.nav_steps,
     )
     return st.best_i, st.best_d, stats, st.cand_q
 
@@ -404,7 +480,7 @@ def knn_query_batch(
     order, inv = _sort_unsort(index, qpos)
     idx_s, d2_s, stats, _ = _knn_sorted(
         index, qpos[order], qid[order], k, window, max_nav, max_iters,
-        executor, window_tables(index, window),
+        executor, window_tables(index, window), nav_table(index),
     )
     return idx_s[inv], jnp.sqrt(d2_s[inv]), stats
 

@@ -114,6 +114,7 @@ from .pipeline import (
     _knn_sorted_impl,
     _resolve_max_nav,
     _sort_unsort,
+    nav_table,
     window_tables,
     zero_stats,
 )
@@ -498,11 +499,13 @@ def _chunked_sweep(index, qpos_s, qid_s, *, k, window, chunk, max_nav,
     nq = qpos_s.shape[0]
     n_chunks = nq // chunk
     tables = window_tables(index, window)  # once for every chunk
+    nav = nav_table(index)
 
     def one_chunk(args):
         qp, qi = args
         return _knn_sorted_impl(
-            index, qp, qi, k, window, max_nav, max_iters, executor, tables
+            index, qp, qi, k, window, max_nav, max_iters, executor, tables,
+            nav,
         )
 
     idx_c, d2_c, stats_c, cq_c = jax.lax.map(
@@ -528,13 +531,15 @@ def _chunked_sweep_masked(index, qpos_s, qid_s, n_live_chunks, *, k, window,
     nq = qpos_s.shape[0]
     n_chunks = nq // chunk
     tables = window_tables(index, window)  # once for every chunk
+    nav = nav_table(index)
 
     def one_chunk(args):
         qp, qi, live = args
 
         def real(_):
             return _knn_sorted_impl(
-                index, qp, qi, k, window, max_nav, max_iters, executor, tables
+                index, qp, qi, k, window, max_nav, max_iters, executor, tables,
+                nav,
             )
 
         def dead(_):
@@ -1327,6 +1332,7 @@ def knn_query_batch_chunked(
         candidates=float(aux.stats.candidates),
         leaves_visited=int(aux.stats.leaves_visited),
         windows_fetched=int(aux.stats.windows_fetched),
+        nav_lane_steps=int(aux.stats.nav_lane_steps),
     )
     out = (np.asarray(ii[:nq]), np.asarray(dd[:nq]), stats)
     if with_aux:

@@ -75,8 +75,8 @@ class QuadtreeIndex:
             (this *is* the paper's z_map: leaf key = (c >> 2d) << 2d,
             d = l_max - leaf_level[c]).
     pyramid: flattened i32 array of quadrant populations at every level
-            (``pyr[pyramid_offset(l) + z]``); used for empty-block skipping during
-            navigation.
+            (``pyr[pyramid_offset(l) + z]``); ``starts`` and the leaf levels
+            derive from it.
     l_max:   static int — maximum quadtree depth.
     th_quad: static int — max objects per leaf (split threshold).
     """
@@ -106,16 +106,9 @@ class QuadtreeIndex:
         return 4**self.l_max
 
 
-def pyramid_offset(level):
-    """Start of level ``level`` inside the flattened pyramid: (4**l - 1) / 3.
-
-    Works for both static ints and traced int arrays — this is what lets the
-    navigation loop index the pyramid at a *dynamic* level (rolled loops keep the
-    compiled program small).
-    """
-    return ((1 << (2 * level)) - 1) // 3 if isinstance(level, int) else (
-        (jnp.left_shift(jnp.int32(1), 2 * level) - 1) // 3
-    )
+def pyramid_offset(level: int) -> int:
+    """Start of level ``level`` inside the flattened pyramid: (4**l - 1) / 3."""
+    return ((1 << (2 * level)) - 1) // 3
 
 
 def _count_pyramid(codes: jnp.ndarray, l_max: int) -> jnp.ndarray:
