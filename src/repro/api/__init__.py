@@ -14,8 +14,10 @@ Speak in sessions, deltas and in-flight ticks, not snapshots:
         res = handle.result()                          # (Q, k) lazily
 
 The execution core underneath is :mod:`repro.core` (`_tick_step`, the
-ExecutionPlan/QueryExecutor seams); ``repro.core.TickEngine`` remains as a
-deprecation shim over a session.
+ExecutionPlan/QueryExecutor seams).  A session is configured by exactly one
+:class:`ServiceSpec`; ``KnnSession.process_tick`` is the blocking snapshot
+call (ingest + ``set_queries`` + ``submit().result()``) for callers that
+hold whole frames.
 """
 from .handles import QueryHandle, TickHandle
 from .session import KnnSession

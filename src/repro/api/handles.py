@@ -12,7 +12,7 @@ Host collection is ONE batched transfer: ``result()`` pulls ``nn_idx``,
 ``nn_dist`` and the per-shard counters through a single ``jax.device_get``
 instead of separate blocking ``np.asarray`` syncs (each sync pays the full
 dispatch-queue drain; batching them collapsed the dominant steady-tick host
-cost measured in BENCH_serving.json).  Pipelines that consume results
+cost, measured on a CPU host).  Pipelines that consume results
 on-device skip the transfer entirely with ``result(materialize=False)``.
 
 What ``result()`` fetches is the spec's ``collect`` mode (DESIGN.md §14):
@@ -22,8 +22,8 @@ sink's O(Q)/O(1) :class:`~repro.api.sink.TickAggregates` (``nn_idx``/
 finalize scalars the session already read are the whole host footprint.
 ``TickResult.collect_s`` records the transfer time each mode actually paid,
 attributed to the tick whose ``result()`` materialized it (NOT the tick
-whose ``submit()`` happened to overlap it), so BENCH host-collect columns
-stay honest under overlapped submission.  ``result()`` first drains the
+whose ``submit()`` happened to overlap it), so host-collect times stay
+honest under overlapped submission.  ``result()`` first drains the
 device computation (``block_until_ready``) *outside* the timed window, so
 ``collect_s`` is pure host materialization cost — on a CPU host, where
 device compute shares the cores, folding the compute drain into the collect
@@ -144,7 +144,7 @@ class TickHandle:
         """Block until this tick's device outputs are computed — NO transfer.
 
         The wait is device-compute drain, not host collection: callers that
-        want the two costs separated (benchmarks, latency-sensitive serving
+        want the two costs separated (bench/, latency-sensitive serving
         loops) call this first, then ``result()``, whose ``collect_s`` then
         times only the materialization.  Idempotent; a no-op once the tick
         has materialized.

@@ -10,7 +10,7 @@ speaks exactly that language:
   stable :class:`~repro.api.handles.QueryHandle` groups.  The padded device
   batch is (re)staged only when the registry changes; unchanged query sets
   ride across ticks with zero host work (``set_queries`` is the bulk
-  snapshot fallback used by the ``TickEngine`` shim).
+  snapshot fallback that :meth:`KnnSession.process_tick` uses).
 * **Delta object updates** — ``update_objects(ids, positions)`` scatters
   moved objects into the device-resident positions buffer
   (:func:`repro.core.ticks.scatter_positions`; functional, so an in-flight
@@ -27,8 +27,8 @@ speaks exactly that language:
   paper's pipeline.  Drift-rebuild bookkeeping is *finalized* per tick at
   the earlier of ``result(τ)`` and ``submit(τ+1)``, reading back only two
   scalars — so the decision sequence is identical to the blocking loop and
-  the session is bit-identical to the snapshot ``TickEngine`` path (pinned
-  by tests/test_api.py).
+  the session is bit-identical to a second session fed whole snapshots
+  through ``process_tick`` (pinned by tests/test_api.py).
 
 The execution core is unchanged: every tick is still the ONE jitted device
 program :func:`repro.core.ticks._tick_step` (reindex + the plan's chunked
@@ -70,11 +70,11 @@ class _QueryRegistry:
 
     Rows are kept contiguous (drops compact); padding rows clone the last
     active query with qid = -2 — the exact :func:`repro.core.plan.pad_queries`
-    convention of the snapshot path, which is what makes session results
-    bit-identical to ``TickEngine``'s.  ``owner`` maps each row to the
-    :class:`QueryHandle` that registered it (-1 for bulk ``set_queries``
-    rows); handles survive compaction because membership is by owner id,
-    not by row position.
+    convention of the one-shot path (``knn_query_batch_chunked``), which is
+    what makes session results bit-identical to it.  ``owner`` maps each
+    row to the :class:`QueryHandle` that registered it (-1 for bulk
+    ``set_queries`` rows); handles survive compaction because membership is
+    by owner id, not by row position.
     """
 
     def __init__(self, multiple: int):
@@ -861,11 +861,14 @@ class KnnSession:
         )
 
     def process_tick(self, positions, qpos, qid=None):
-        """Blocking snapshot convenience: ingest + set_queries + submit + result.
+        """One blocking tick from whole frames: ingest + set_queries + submit
+        + result.
 
-        ``wall_s`` here is measured from the top of the call — staging
-        included — matching the pre-session ``TickEngine.process_tick``
-        boundary, so BENCH rows built on it stay comparable across PRs.
+        For callers that hold a full position snapshot and a full query
+        batch each tick (tests, one-off scripts) rather than persistent
+        handles and deltas; results are bitwise those of the handle loop.
+        ``wall_s`` here is measured from the top of the call, staging
+        included.
         """
         t0 = time.perf_counter()
         self.ingest_objects(positions)

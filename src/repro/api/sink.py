@@ -1,6 +1,6 @@
 """ResultSink — on-device per-tick result consumers (DESIGN.md §14).
 
-The steady-state serving bottleneck measured in BENCH_serving.json was never
+The steady-state serving bottleneck measured on a CPU host was never
 the sweep: it was ``result()`` draining the dispatch queue and shipping the
 ``(Q, k)`` neighbour lists to the host every tick.  Most monitoring consumers
 do not need the lists — they need *aggregates*: how much did each query's

@@ -1,19 +1,27 @@
 """ServiceSpec — the declarative description of one k-NN serving session.
 
-A spec subsumes :class:`repro.core.ticks.EngineConfig` (algorithm + device
-layout knobs) **and** the workload geometry (the squared region ``G`` the
-paper's index partitions: ``origin`` + ``side``) that used to ride as loose
-``TickEngine`` constructor arguments, plus the session-only staging knob
-``delta_pad``.  It is frozen, hashable and eagerly validated: unknown
-``backend``/``plan`` names and inconsistent sweep geometry raise at
-construction time with the full registry listing, instead of surfacing as a
-deep registry ``KeyError`` on the first tick.
+A spec is the one configuration of a session: the algorithm and device
+layout knobs, the workload geometry (the squared region ``G`` the paper's
+index partitions: ``origin`` + ``side``), the staging knob ``delta_pad`` and
+the result ``collect`` mode.  It is frozen, hashable and eagerly validated:
+this is the layer that takes outside input, so unknown registry names and
+inconsistent sweep geometry raise at construction time with the full
+registry listing, instead of surfacing as a deep registry ``KeyError`` on
+the first tick.  The registries themselves live below, in :mod:`repro.core`
+and :mod:`repro.kernels`.
 """
 from __future__ import annotations
 
 import dataclasses
 
-from repro.core.ticks import EngineConfig, validate_engine_params
+from repro.core.balance import partitioner_names
+from repro.core.executor import (
+    available_backends,
+    available_plans,
+    available_precisions,
+)
+from repro.core.ticks import MAINTENANCE_MODES
+from repro.kernels import merge_backend_names
 
 __all__ = ["ServiceSpec", "COLLECT_MODES"]
 
@@ -30,8 +38,8 @@ COLLECT_MODES = ("full", "stats", "none")
 class ServiceSpec:
     """Everything a :class:`repro.api.KnnSession` needs, declared up front.
 
-    Algorithm / layout fields mirror ``EngineConfig`` one-to-one (same
-    defaults); ``origin``/``side`` pin the region ``G`` of the quadtree;
+    Algorithm / layout fields configure the tick program and its plan;
+    ``origin``/``side`` pin the region ``G`` of the quadtree;
     ``delta_pad`` rounds ``update_objects`` batches up to a fixed multiple
     (sentinel-padded, dropped by the scatter) so every delta size reuses one
     compiled scatter program.
@@ -79,13 +87,85 @@ class ServiceSpec:
     collect: str = "full"
 
     def __post_init__(self):
-        validate_engine_params(
-            k=self.k, window=self.window, chunk=self.chunk,
-            backend=self.backend, plan=self.plan, mesh_shape=self.mesh_shape,
-            partitioner=self.partitioner, precision=self.precision,
-            merge=self.merge, maintenance=self.maintenance,
-            churn_budget=self.churn_budget,
-        )
+        """Reject unknown registry names and geometry the sweep cannot serve.
+
+        Names are checked against the registries (``backend``, ``plan``,
+        ``partitioner``, ``precision``, ``merge``, ``maintenance``,
+        ``collect``) and the error lists the registered ones; geometry must
+        satisfy ``chunk % window == 0`` and ``k <= chunk``.  Instances
+        (``QueryExecutor`` / ``ExecutionPlan`` / ``Partitioner``) pass
+        through unchecked — they validated themselves on construction.
+        """
+        if (isinstance(self.backend, str)
+                and self.backend not in available_backends()):
+            raise ValueError(
+                f"unknown backend {self.backend!r}; registered SCAN "
+                f"backends: {available_backends()}"
+            )
+        if isinstance(self.plan, str) and self.plan not in available_plans():
+            raise ValueError(
+                f"unknown execution plan {self.plan!r}; registered plans: "
+                f"{available_plans()}"
+            )
+        if (isinstance(self.partitioner, str)
+                and self.partitioner not in partitioner_names()):
+            raise ValueError(
+                f"unknown partitioner {self.partitioner!r}; registered "
+                f"partitioners: {partitioner_names()}"
+            )
+        if (self.precision is not None
+                and self.precision not in available_precisions()):
+            raise ValueError(
+                f"unknown precision {self.precision!r}; one of "
+                f"{available_precisions()}"
+            )
+        if (isinstance(self.merge, str)
+                and self.merge not in merge_backend_names()):
+            raise ValueError(
+                f"unknown merge backend {self.merge!r}; registered MERGE "
+                f"backends: {merge_backend_names()}"
+            )
+        if (self.maintenance is not None
+                and self.maintenance not in MAINTENANCE_MODES):
+            raise ValueError(
+                f"unknown maintenance mode {self.maintenance!r}; one of "
+                f"{MAINTENANCE_MODES}"
+            )
+        if (self.churn_budget is not None
+                and not 0.0 < self.churn_budget <= 1.0):
+            raise ValueError(
+                f"churn_budget must be in (0, 1], got {self.churn_budget!r} "
+                "(fraction of N moved since the last full refresh at which "
+                "the incremental path defers to a full reindex)"
+            )
+        if self.k < 1:
+            raise ValueError(f"k must be >= 1, got {self.k}")
+        if self.window < 1:
+            raise ValueError(f"window must be >= 1, got {self.window}")
+        if self.chunk < 1 or self.chunk % self.window != 0:
+            raise ValueError(
+                f"chunk ({self.chunk}) must be a positive multiple of window "
+                f"({self.window})"
+            )
+        if self.k > self.chunk:
+            raise ValueError(f"k ({self.k}) must be <= chunk ({self.chunk})")
+        mesh = self.mesh_shape
+        if isinstance(mesh, (tuple, list)):
+            if len(mesh) != 2 or any(
+                not isinstance(d, int) or d < 1 for d in mesh
+            ):
+                raise ValueError(
+                    "mesh_shape tuples must be a (query, object) pair of "
+                    f"positive ints, got {mesh!r}"
+                )
+            if isinstance(self.plan, str) and self.plan != "hybrid":
+                raise ValueError(
+                    f"plan {self.plan!r} lays a 1-D mesh; mesh_shape must be "
+                    f"an int, got {tuple(mesh)!r} (2-D shapes are for "
+                    "plan='hybrid')"
+                )
+        elif mesh is not None and mesh < 1:
+            raise ValueError(f"mesh_shape must be >= 1, got {mesh}")
         if self.collect not in COLLECT_MODES:
             raise ValueError(
                 f"unknown collect mode {self.collect!r}; one of {COLLECT_MODES}"
@@ -96,37 +176,3 @@ class ServiceSpec:
             raise ValueError(f"origin must be an (x, y) pair, got {self.origin!r}")
         if self.delta_pad < 1:
             raise ValueError(f"delta_pad must be >= 1, got {self.delta_pad}")
-
-    def engine_config(self) -> EngineConfig:
-        """The EngineConfig subset of this spec (for core-layer consumers)."""
-        return EngineConfig(
-            k=self.k, th_quad=self.th_quad, l_max=self.l_max,
-            window=self.window, chunk=self.chunk,
-            rebuild_factor=self.rebuild_factor, region_pad=self.region_pad,
-            backend=self.backend, plan=self.plan, mesh_shape=self.mesh_shape,
-            partitioner=self.partitioner, precision=self.precision,
-            merge=self.merge, maintenance=self.maintenance,
-            churn_budget=self.churn_budget, max_iters=self.max_iters,
-        )
-
-    @classmethod
-    def from_engine(
-        cls,
-        cfg: EngineConfig,
-        *,
-        origin: tuple[float, float] = (0.0, 0.0),
-        side: float = SIDE_DEFAULT,
-        delta_pad: int = 1024,
-    ) -> "ServiceSpec":
-        """Lift an EngineConfig (+ the old loose geometry args) into a spec."""
-        return cls(
-            k=cfg.k, th_quad=cfg.th_quad, l_max=cfg.l_max, window=cfg.window,
-            chunk=cfg.chunk, rebuild_factor=cfg.rebuild_factor,
-            region_pad=cfg.region_pad, backend=cfg.backend, plan=cfg.plan,
-            mesh_shape=cfg.mesh_shape, partitioner=cfg.partitioner,
-            precision=cfg.precision, merge=cfg.merge,
-            maintenance=cfg.maintenance, churn_budget=cfg.churn_budget,
-            max_iters=cfg.max_iters,
-            origin=(float(origin[0]), float(origin[1])), side=float(side),
-            delta_pad=delta_pad,
-        )

@@ -127,7 +127,7 @@ def balanced_boundaries(costs, num_shards: int, capacity: int) -> jnp.ndarray:
 
 
 def straggler_gap(shard_work) -> float:
-    """max/mean per-shard work — THE skew metric benchmarks report (s7).
+    """max/mean per-shard work — THE skew metric of the partitioners.
 
     1.0 = perfectly balanced; R = one shard does everything.  Computed on
     host from the per-shard candidate counters a tick returns
@@ -177,7 +177,7 @@ class Partitioner:
         function of the unit count).  Every plan now runs ONE
         boundary-driven body for both partitioners (the ``sharded`` plan's
         split-``in_specs`` fast path was retired with DESIGN.md §14); the
-        flag survives as a cheap query for tests and benchmarks that want
+        flag survives as a cheap query for tests that want
         to know whether boundaries can move between ticks."""
         return False
 
@@ -274,7 +274,7 @@ class CostBalancedPartitioner(Partitioner):
 
 
 # --------------------------------------------------------------------------
-# partitioner registry — spec/config/benchmarks select one by name
+# partitioner registry — the session spec selects one by name
 # --------------------------------------------------------------------------
 
 _PARTITIONERS: dict = {}

@@ -3,8 +3,8 @@
 The pipeline (core layer) never selects neighbours itself: every SCAN
 iteration hands its gathered candidate window to an executor, which dispatches
 to a registered kernel-layer backend (DESIGN.md §6).  The serving layer
-(:class:`repro.core.ticks.TickEngine`) and the benchmarks pick the backend by
-name (``EngineConfig.backend`` / ``--backend``), so swapping the selection
+(:class:`repro.api.KnnSession`) picks the backend by name
+(``ServiceSpec.backend`` / ``--backend``), so swapping the selection
 strategy — XLA top-k, the fused Pallas kernel, full-sort brute force, or any
 future sharded/approximate variant — touches no pipeline code.
 
@@ -41,19 +41,19 @@ __all__ = [
 
 
 def available_backends() -> tuple[str, ...]:
-    """Names accepted by ``resolve_executor`` / ``EngineConfig.backend``."""
+    """Names accepted by ``resolve_executor`` / ``ServiceSpec.backend``."""
     return scan_backend_names()
 
 
 def available_plans() -> tuple[str, ...]:
-    """Names accepted by ``resolve_plan`` / ``EngineConfig.plan``."""
+    """Names accepted by ``resolve_plan`` / ``ServiceSpec.plan``."""
     from .plan import plan_names  # lazy: plan.py imports pipeline -> executor
 
     return plan_names()
 
 
 def available_partitioners() -> tuple[str, ...]:
-    """Names accepted by ``EngineConfig.partitioner`` — the third seam axis
+    """Names accepted by ``ServiceSpec.partitioner`` — the third seam axis
     (work splitting), configured at the same boundary as backend and plan."""
     from .balance import partitioner_names
 
@@ -61,7 +61,7 @@ def available_partitioners() -> tuple[str, ...]:
 
 
 def available_precisions() -> tuple[str, ...]:
-    """Names accepted by ``EngineConfig.precision`` — the sweep's numeric
+    """Names accepted by ``ServiceSpec.precision`` — the sweep's numeric
     mode (DESIGN.md §14), configured at the same boundary as the backend."""
     return PRECISIONS
 

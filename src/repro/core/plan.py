@@ -162,7 +162,7 @@ class PlanAux(NamedTuple):
     ``shard_candidates`` / ``shard_iterations``
         (R_total,) per-shard measured counters, one entry per mesh device
         (R_total = 1 for ``single``); ``max/mean`` of the candidates row is
-        the straggler gap benchmarks report (``balance.straggler_gap``).
+        the straggler gap (``balance.straggler_gap``).
     ``qcost_next``
         (Q_padded,) f32 per-query cost EMA in the CALLER's row order — the
         session persists it across ticks and feeds it back as ``qcost``.
@@ -199,10 +199,10 @@ def pad_queries(qpos, qid, multiple: int):
     the sharded plan — one pad, host-side, so every device shard is a whole
     number of identical fixed-shape chunks.  Padding rows clone the last
     query with qid=-2; callers strip them after the gather via ``[:Q]`` (the
-    global unsort returns them to the tail).  Both the snapshot path
-    (``TickEngine``/``knn_query_batch_chunked``) and the session registry pad
-    through HERE, which is what makes their padded batches — and hence their
-    results and stats — bit-identical.
+    global unsort returns them to the tail).  Both the one-shot path
+    (``knn_query_batch_chunked``) and the session registry pad through HERE,
+    which is what makes their padded batches — and hence their results and
+    stats — bit-identical.
     """
     import numpy as np
 
@@ -1075,7 +1075,7 @@ class HybridPlan(ExecutionPlan):
 
 
 # --------------------------------------------------------------------------
-# plan registry — serving/benchmarks/examples select a plan by name
+# plan registry — the session spec and the examples select a plan by name
 # --------------------------------------------------------------------------
 
 # name -> factory(num_devices | None, Partitioner, merge | None) -> ExecutionPlan
@@ -1093,7 +1093,7 @@ def register_plan(name: str):
 
 
 def plan_names() -> tuple[str, ...]:
-    """Names accepted by ``resolve_plan`` / ``EngineConfig.plan``."""
+    """Names accepted by ``resolve_plan`` / ``ServiceSpec.plan``."""
     return tuple(sorted(_PLANS))
 
 
@@ -1157,7 +1157,7 @@ def resolve_plan(plan, *, num_devices=None, partitioner=None,
                  merge=None) -> ExecutionPlan:
     """Name | ExecutionPlan | None -> ExecutionPlan (default: single).
 
-    ``num_devices`` parameterizes named plans (``EngineConfig.mesh_shape``):
+    ``num_devices`` parameterizes named plans (``ServiceSpec.mesh_shape``):
     an int for the 1-D plans (``sharded`` / ``object_sharded``, default every
     visible device) or a ``(query, object)`` pair for ``hybrid`` (default the
     most balanced factorization of the device count).  ``partitioner`` is a
@@ -1300,7 +1300,7 @@ def knn_query_batch_chunked(
     from the index's sorted order — valid because a hand-built index IS
     current for itself).  ``with_aux=True`` appends the host-materialized
     :class:`PlanAux` (per-shard counters, cost EMA, object boundaries) to
-    the return tuple — the benchmarks' straggler-gap probe.
+    the return tuple — the tests' straggler-gap probe.
     """
     import numpy as np
 

@@ -1,16 +1,12 @@
 """Iterated batch processing of k-NN queries over ticks (paper Sec. 2.2/2.3).
 
-Since the session-API redesign (DESIGN.md §11) this module is the **execution
-core** under the public serving facade :mod:`repro.api`: it owns the jitted
-per-tick device program (:func:`_tick_step`), the device-side delta-ingest
-primitive (:func:`scatter_positions`), the engine configuration
-(:class:`EngineConfig`, eagerly validated) and the per-tick result record
-(:class:`TickResult`).  The stateful serving loop — persistent query
-registry, delta object updates, overlapped submit — lives in
-:class:`repro.api.KnnSession`; :class:`TickEngine` remains here as a **thin
-deprecation shim** over a session so PR-1/PR-2 call sites keep working
-unchanged (``TickEngine.run`` ≡ a blocking ``KnnSession`` loop, pinned by
-tests/test_api.py).
+This module is the **execution core** under the serving facade
+:mod:`repro.api` (DESIGN.md §11): it owns the jitted per-tick device program
+(:func:`_tick_step`), the device-side delta-ingest primitive
+(:func:`scatter_positions`), the delta routing helpers and the per-tick
+result record (:class:`TickResult`).  The stateful serving loop — persistent
+query registry, delta object updates, overlapped submit — lives in
+:class:`repro.api.KnnSession`, configured by :class:`repro.api.ServiceSpec`.
 
 The whole steady-state tick is ONE jitted device program (:func:`_tick_step`,
 DESIGN.md §8/§11): stage (ii) index refresh (object re-sort + interval/
@@ -33,15 +29,15 @@ the amount yielded during past, recent ticks".  The trigger is *computed on
 device* from the tick's candidate counter and crosses to the host as a single
 scalar together with the results.
 
-The SCAN backend is configurable per engine (``EngineConfig.backend``; see
+The SCAN backend is configurable per session (``ServiceSpec.backend``; see
 ``repro.core.executor.available_backends``), and so is the device layout of
-the query sweep (``EngineConfig.plan`` / ``mesh_shape``; DESIGN.md §10/§12):
+the query sweep (``ServiceSpec.plan`` / ``mesh_shape``; DESIGN.md §10/§12):
 ``sharded`` replicates the index across a 1-D ``("query",)`` mesh and splits
 the Morton-sorted batch with ``shard_map``; ``object_sharded`` splits the
 *object* set into Morton-contiguous slices with a local quadtree per device
 and merge-reduces per-query lists; ``hybrid`` composes both on a 2-D
 ``("query", "object")`` mesh (``mesh_shape`` becomes a pair).  How each
-split axis is CUT is the partitioner's job (``EngineConfig.partitioner``;
+split axis is CUT is the partitioner's job (``ServiceSpec.partitioner``;
 DESIGN.md §13): ``equal`` keeps the static equal-count splits,
 ``cost_balanced`` re-balances boundaries every tick from the count-pyramid
 seed plus the per-query cost EMA threaded through the step.  Per-shard
@@ -54,9 +50,7 @@ the session's delta routing.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from functools import partial
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -64,17 +58,13 @@ import numpy as np
 
 from repro.tracing import stage
 
-from .balance import partitioner_names
-from .executor import QueryExecutor, available_backends, available_plans
+from .executor import QueryExecutor
 from .plan import ExecutionPlan
 from .quadtree import reindex_objects, reindex_objects_delta
 
 __all__ = [
-    "TickEngine",
     "TickResult",
-    "EngineConfig",
     "MAINTENANCE_MODES",
-    "validate_engine_params",
     "scatter_positions",
     "object_shard_of",
     "route_delta",
@@ -90,137 +80,6 @@ __all__ = [
 # ticks with no position change — which is a session scheduling decision,
 # not a user-facing policy.)
 MAINTENANCE_MODES = ("rebuild", "incremental")
-
-
-def validate_engine_params(*, k, window, chunk, backend, plan, mesh_shape=None,
-                           partitioner=None, precision=None, merge=None,
-                           maintenance=None, churn_budget=None):
-    """Eager validation shared by ``EngineConfig`` and ``repro.api.ServiceSpec``.
-
-    Raises ``ValueError`` with the full registry listing for unknown
-    ``backend``/``plan``/``partitioner``/``precision``/``merge`` names
-    (instead of the deep registry ``KeyError`` that used to surface on first
-    use), and rejects geometry that the chunked sweep cannot serve
-    (``chunk`` not a multiple of ``window``, ``k > chunk``).  Instances
-    (``QueryExecutor`` / ``ExecutionPlan`` / ``Partitioner``) pass through
-    unchecked — they validated themselves on construction.
-    """
-    from .executor import available_precisions
-
-    if isinstance(backend, str) and backend not in available_backends():
-        raise ValueError(
-            f"unknown backend {backend!r}; registered SCAN backends: "
-            f"{available_backends()}"
-        )
-    if isinstance(plan, str) and plan not in available_plans():
-        raise ValueError(
-            f"unknown execution plan {plan!r}; registered plans: "
-            f"{available_plans()}"
-        )
-    if isinstance(partitioner, str) and partitioner not in partitioner_names():
-        raise ValueError(
-            f"unknown partitioner {partitioner!r}; registered partitioners: "
-            f"{partitioner_names()}"
-        )
-    if precision is not None and precision not in available_precisions():
-        raise ValueError(
-            f"unknown precision {precision!r}; one of {available_precisions()}"
-        )
-    if merge is not None:
-        from repro.kernels import merge_backend_names
-
-        if isinstance(merge, str) and merge not in merge_backend_names():
-            raise ValueError(
-                f"unknown merge backend {merge!r}; registered MERGE "
-                f"backends: {merge_backend_names()}"
-            )
-    if maintenance is not None and maintenance not in MAINTENANCE_MODES:
-        raise ValueError(
-            f"unknown maintenance mode {maintenance!r}; one of "
-            f"{MAINTENANCE_MODES}"
-        )
-    if churn_budget is not None and not (0.0 < churn_budget <= 1.0):
-        raise ValueError(
-            f"churn_budget must be in (0, 1], got {churn_budget!r} "
-            "(fraction of N moved since the last full refresh at which the "
-            "incremental path defers to a full reindex)"
-        )
-    if k < 1:
-        raise ValueError(f"k must be >= 1, got {k}")
-    if window < 1:
-        raise ValueError(f"window must be >= 1, got {window}")
-    if chunk < 1 or chunk % window != 0:
-        raise ValueError(
-            f"chunk ({chunk}) must be a positive multiple of window ({window})"
-        )
-    if k > chunk:
-        raise ValueError(f"k ({k}) must be <= chunk ({chunk})")
-    if mesh_shape is not None:
-        if isinstance(mesh_shape, (tuple, list)):
-            if len(mesh_shape) != 2 or any(
-                not isinstance(d, int) or d < 1 for d in mesh_shape
-            ):
-                raise ValueError(
-                    "mesh_shape tuples must be a (query, object) pair of "
-                    f"positive ints, got {mesh_shape!r}"
-                )
-            if isinstance(plan, str) and plan != "hybrid":
-                raise ValueError(
-                    f"plan {plan!r} lays a 1-D mesh; mesh_shape must be an "
-                    f"int, got {tuple(mesh_shape)!r} (2-D shapes are for "
-                    "plan='hybrid')"
-                )
-        elif mesh_shape < 1:
-            raise ValueError(f"mesh_shape must be >= 1, got {mesh_shape}")
-
-
-@dataclasses.dataclass(frozen=True)
-class EngineConfig:
-    k: int = 32
-    th_quad: int = 192
-    l_max: int = 8
-    window: int = 256
-    chunk: int = 8192
-    rebuild_factor: float = 2.0  # rebuild partition when work grows by this factor
-    region_pad: float = 1e-3
-    backend: str = "dense_topk"  # SCAN backend (executor.available_backends())
-    plan: str = "single"  # execution plan (executor.available_plans())
-    # devices on the plan's mesh: an int for the 1-D plans (sharded /
-    # object_sharded), a (query, object) pair for hybrid; None = all devices
-    # (hybrid: the most balanced factorization of the device count)
-    mesh_shape: int | tuple[int, int] | None = None
-    # work partitioner for the plan's split axes (balance.partitioner_names():
-    # "equal" = the static equal-count splits, "cost_balanced" = skew-adaptive
-    # boundaries from the count-pyramid seed + measured-work EMA)
-    partitioner: str = "equal"
-    # sweep numeric mode (executor.available_precisions(); DESIGN.md §14):
-    # "fp32" = exact; "mixed" = bf16 widened-radius prefilter + fp32 refine,
-    # bitwise-identical results
-    precision: str = "fp32"
-    # MERGE backend for the object-axis reduce (kernels.merge_backend_names();
-    # "dense_merge" = binary tree of pairwise kernels, "fused_multi" = one
-    # multi-way kernel per query row — no HBM round-trip between rounds)
-    merge: str = "dense_merge"
-    # index-maintenance policy (MAINTENANCE_MODES; DESIGN.md §15):
-    # "rebuild" = full stage-(ii) refresh every dirty tick; "incremental" =
-    # delta Morton recode + sorted-run splice + pyramid scatter-add for the
-    # moved rows only, bitwise-identical to "rebuild" at every tick
-    maintenance: str = "rebuild"
-    # incremental only: fraction of N moved since the last full refresh at
-    # which the session defers to a full reindex (generalizes the spirit of
-    # rebuild_factor to stage (ii); the crossover where a full O(N log N)
-    # sort beats delta accounting)
-    churn_budget: float = 0.25
-    max_iters: int = 100_000
-
-    def __post_init__(self):
-        validate_engine_params(
-            k=self.k, window=self.window, chunk=self.chunk,
-            backend=self.backend, plan=self.plan, mesh_shape=self.mesh_shape,
-            partitioner=self.partitioner, precision=self.precision,
-            merge=self.merge, maintenance=self.maintenance,
-            churn_budget=self.churn_budget,
-        )
 
 
 @dataclasses.dataclass
@@ -341,7 +200,7 @@ def _tick_step(
     The step deliberately does NOT donate the incoming index: donated
     arguments make the host-side dispatch *synchronous* on this runtime (the
     call blocks for the whole device step instead of returning a future,
-    measured while building benchmarks/s6_serving.py), which would serialize
+    measured on the CPU backend), which would serialize
     host staging against device compute and defeat the session API's
     submit/result overlap.  The in-place refresh saved one index-sized
     allocation per tick; the overlap is worth far more, and XLA's allocator
@@ -525,76 +384,3 @@ def scatter_positions(positions, ids, new_pos):
     into a fresh one (double-buffering).
     """
     return positions.at[ids].set(new_pos, mode="drop")
-
-
-class TickEngine:
-    """Deprecation shim: the PR-1/PR-2 snapshot-per-tick API over a session.
-
-    ``process_tick`` stages a full position snapshot + a full query batch and
-    blocks for results, exactly as before — but it now routes through
-    :class:`repro.api.KnnSession` (snapshot ingest + bulk ``set_queries`` +
-    ``submit().result()``), so there is a single serving implementation.
-    New code should construct a ``KnnSession`` from a ``ServiceSpec`` and use
-    persistent query handles + delta object updates instead.
-    """
-
-    def __init__(self, cfg: EngineConfig, origin=(0.0, 0.0), side: float = 22_500.0):
-        warnings.warn(
-            "TickEngine is a deprecation shim over repro.api.KnnSession; "
-            "migrate to the session API (ServiceSpec + KnnSession)",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        from repro.api import KnnSession, ServiceSpec  # lazy: api sits above core
-
-        self.cfg = cfg
-        self.origin = np.asarray(origin, np.float32)
-        self.side = float(side)
-        self.session = KnnSession(
-            ServiceSpec.from_engine(
-                cfg, origin=(float(self.origin[0]), float(self.origin[1])),
-                side=self.side,
-            )
-        )
-        self.tick = 0
-        self.history: list[TickResult] = []
-
-    # legacy attribute surface (benchmarks/examples read these)
-    @property
-    def executor(self) -> QueryExecutor:
-        return self.session.executor
-
-    @property
-    def plan(self) -> ExecutionPlan:
-        return self.session.plan
-
-    @property
-    def index(self):
-        return self.session.index
-
-    def process_tick(
-        self, positions: np.ndarray, qpos: np.ndarray, qid: np.ndarray | None
-    ) -> TickResult:
-        """One iteration of the repeated spatial join: (P_tau, Q_tau) -> R_tau."""
-        res = self.session.process_tick(positions, qpos, qid)
-        self.tick += 1
-        self.history.append(res)
-        return res
-
-    def run(
-        self,
-        workload,
-        ticks: int,
-        query_rate: float = 1.0,
-        on_tick: Callable[[TickResult], None] | None = None,
-    ):
-        """Drive a MovingObjectWorkload for ``ticks`` ticks (paper: 30)."""
-        out = []
-        for _ in range(ticks):
-            qpos, qid = workload.query_batch(query_rate)
-            res = self.process_tick(workload.positions(), qpos, qid)
-            out.append(res)
-            if on_tick:
-                on_tick(res)
-            workload.advance()
-        return out

@@ -7,9 +7,9 @@ along the edges of a network; we synthesize a jittered-grid network since the
 San Francisco edge file is not available offline — noted in DESIGN.md §9).
 
 Two further presets stress the skew axis the paper's headline claim covers
-("highly skewed spatial distributions") — shared by the partitioner
-benchmarks (benchmarks/s7_skew.py) and the property harness
-(tests/test_properties.py) instead of each hand-rolling skewed clouds:
+("highly skewed spatial distributions") — shared by the partitioner tests
+and the property harness (tests/test_properties.py) instead of each
+hand-rolling skewed clouds:
 
 * *zipf* — ``clusters`` hotspot centers whose populations follow a Zipf law
   with exponent ``zipf_a`` (most mass in one tiny region: deep trees, long

@@ -1,3 +1,3 @@
-from .mesh import make_local_mesh, make_production_mesh, make_query_mesh
+from .mesh import make_local_mesh, make_query_mesh
 
-__all__ = ["make_local_mesh", "make_production_mesh", "make_query_mesh"]
+__all__ = ["make_local_mesh", "make_query_mesh"]

@@ -1,17 +1,13 @@
-"""Production mesh construction (single-pod 16x16 and multi-pod 2x16x16).
+"""Device meshes: the k-NN plans' meshes and a small local mesh for tests.
 
-A FUNCTION, not a module-level constant — importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS *before* any jax init).
+FUNCTIONS, not module-level constants — importing this module never touches
+jax device state (a forced host device count must be set before jax init).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 __all__ = [
-    "forced_cpu_env",
-    "make_production_mesh",
     "make_local_mesh",
     "make_query_mesh",
     "make_object_mesh",
@@ -20,33 +16,8 @@ __all__ = [
 ]
 
 
-def forced_cpu_env(devices: int) -> dict:
-    """Environment for a child process on ``devices`` forced CPU devices.
-
-    ``JAX_PLATFORMS=cpu`` keeps the child off any accelerator: a TPU chip
-    belongs to one process, which may be the parent.  The XLA flag splits
-    the host into ``devices`` CPU devices; it only takes effect in a process
-    that has not initialized JAX yet.
-    """
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}"
-    ).strip()
-    return env
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_local_mesh(data: int = 1, model: int = 1, pod: int = 1):
+def make_local_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many (possibly fake) local devices exist — tests."""
-    if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
     return jax.make_mesh((data, model), ("data", "model"))
 
 

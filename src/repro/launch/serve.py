@@ -1,17 +1,13 @@
-"""Serving drivers.
+"""The k-NN serving driver: ``python -m repro.launch.serve knn``.
 
-Two modes, matching the paper's kind (query serving) and the LM stack:
-
-  knn   — the paper's end-to-end service: repeated k-NN query batches over
-          moving objects, one batch per tick, served through the session
-          facade (repro.api.KnnSession: persistent queries, delta object
-          ingest, optional overlapped submit; DESIGN.md §11).
-  lm    — batched LM token serving: prefill a batch of prompts, then decode
-          tokens with the per-layer KV cache / recurrent state.
+The paper's end-to-end service: repeated k-NN query batches over moving
+objects, one batch per tick, served through the session facade
+(repro.api.KnnSession: persistent queries, delta object ingest; DESIGN.md
+§11), or with ``--tenants N`` through one shared KnnServer tick program
+(repro.serve; DESIGN.md §16).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve knn --objects 50000 --ticks 10 --k 32
-  PYTHONPATH=src python -m repro.launch.serve lm --arch rwkv6_3b --smoke --tokens 16
 """
 from __future__ import annotations
 
@@ -19,24 +15,11 @@ import argparse
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.api import KnnSession, ServiceSpec
-from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data import make_workload
-from repro.dist import use_rules
 from repro.launch.compile_cache import use_compile_cache
-from repro.launch.mesh import make_local_mesh
-from repro.models import (
-    decode_step,
-    encode_memory,
-    forward,
-    init_decode_state,
-    init_params,
-    seed_decode_state,
-)
 
 
 def serve_knn(args) -> int:
@@ -136,58 +119,6 @@ def serve_knn_tenants(args, spec) -> int:
     return 0
 
 
-def serve_lm(args) -> int:
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    mesh = make_local_mesh(data=args.data, model=args.model)
-    with use_rules(mesh):
-        params = init_params(cfg, jax.random.PRNGKey(args.seed))
-        rng = np.random.default_rng(args.seed)
-        prompts = jnp.asarray(
-            rng.integers(0, cfg.vocab, size=(args.batch, args.prompt_len)), jnp.int32
-        )
-        batch = {"tokens": prompts}
-        if cfg.family == "encdec":
-            batch["frames"] = jnp.asarray(
-                rng.normal(0, 0.02, (args.batch, args.prompt_len, cfg.d_model)), jnp.float32
-            )
-        if cfg.family == "vlm":
-            batch["img"] = jnp.asarray(
-                rng.normal(0, 0.02, (args.batch, cfg.n_img_tokens, cfg.d_model)), jnp.float32
-            )
-        # prefill: full forward for last-token logits (cache seeding for the
-        # attention families happens token-by-token below for simplicity)
-        t0 = time.time()
-        logits, _ = jax.jit(
-            lambda p, b: forward(p, cfg, b, logits_last_only=True)
-        )(params, batch)
-        tok = jnp.argmax(logits[:, -1, :], -1)[:, None].astype(jnp.int32)
-        print(f"[lm] prefill {args.batch}x{args.prompt_len}: {time.time() - t0:.2f}s")
-
-        state = init_decode_state(cfg, args.batch, args.prompt_len + args.tokens,
-                                  mem_len=args.prompt_len)
-        if cfg.family == "encdec":
-            state = seed_decode_state(cfg=cfg, params=params, state=state,
-                                      memory=encode_memory(params, cfg, batch["frames"]))
-        if cfg.family == "vlm":
-            state = seed_decode_state(cfg=cfg, params=params, state=state,
-                                      memory=batch["img"])
-        step = jax.jit(lambda p, st, t, q: decode_step(p, cfg, st, t, q))
-        out = []
-        t0 = time.time()
-        for i in range(args.tokens):
-            logits, state = step(params, state, tok, jnp.int32(args.prompt_len + i))
-            tok = jnp.argmax(logits[:, -1, :], -1)[:, None].astype(jnp.int32)
-            out.append(np.asarray(tok[:, 0]))
-        dt = time.time() - t0
-        print(
-            f"[lm] decoded {args.tokens} tokens x batch {args.batch}: "
-            f"{dt / args.tokens * 1e3:.1f} ms/token, "
-            f"{args.batch * args.tokens / dt:.1f} tok/s"
-        )
-        print("[lm] sample:", np.stack(out, 1)[0][:16])
-    return 0
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="mode", required=True)
@@ -214,20 +145,9 @@ def main(argv=None) -> int:
                    help="serve N tenants through one shared KnnServer tick "
                         "program (repro.serve); 1 = solo KnnSession")
     k.add_argument("--seed", type=int, default=0)
-    m = sub.add_parser("lm")
-    m.add_argument("--arch", default="rwkv6_3b", choices=list(ARCH_IDS))
-    m.add_argument("--smoke", action="store_true")
-    m.add_argument("--batch", type=int, default=4)
-    m.add_argument("--prompt-len", type=int, default=32)
-    m.add_argument("--tokens", type=int, default=16)
-    m.add_argument("--data", type=int, default=1)
-    m.add_argument("--model", type=int, default=1)
-    m.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    if args.mode == "knn":
-        use_compile_cache()
-        return serve_knn(args)
-    return serve_lm(args)
+    use_compile_cache()
+    return serve_knn(args)
 
 
 if __name__ == "__main__":

@@ -1,4 +1,11 @@
-"""Deterministic fallback for the tiny slice of `hypothesis` the tests use.
+"""Test helpers: a blocking tick loop and a fallback for `hypothesis`.
+
+:func:`run_ticks` drives a session with whole frames of a
+:class:`~repro.data.MovingObjectWorkload`, one blocking
+``KnnSession.process_tick`` per tick.
+
+The rest is a deterministic fallback for the tiny slice of `hypothesis` the
+tests use.
 
 The CI container has no ``hypothesis`` wheel and the tier-1 suite must not
 depend on network installs, so property tests import it through a guard::
@@ -21,7 +28,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-__all__ = ["given", "settings", "strategies"]
+__all__ = ["run_ticks", "given", "settings", "strategies"]
 
 DEFAULT_MAX_EXAMPLES = 20
 
@@ -90,3 +97,18 @@ def given(*strats: _Strategy):
         return run
 
     return deco
+
+
+def run_ticks(session, workload, ticks: int, query_rate: float = 1.0):
+    """``ticks`` blocking ticks of ``workload`` through ``session``.
+
+    Each tick draws the query batch, serves it against the workload's
+    current positions with ``session.process_tick`` and advances the
+    workload.  Returns the list of ``TickResult``.
+    """
+    out = []
+    for _ in range(ticks):
+        qpos, qid = workload.query_batch(query_rate)
+        out.append(session.process_tick(workload.positions(), qpos, qid))
+        workload.advance()
+    return out

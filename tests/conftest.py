@@ -4,8 +4,8 @@ import sys
 
 import pytest
 
-# tests run on the single real CPU device (the dry-run, and only the dry-run,
-# forces 512 placeholder devices — keep that flag OUT of here)
+# tests run on the real CPU devices; a test that needs a forced device count
+# sets it in a child process of its own — keep that flag OUT of here
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Every cached jitted executable pins its captured constants as live device

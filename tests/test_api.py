@@ -1,26 +1,26 @@
 """Session-oriented serving API (repro.api, DESIGN.md §11).
 
-The acceptance contract of the api_redesign PR: the ``KnnSession`` delta-
-update and overlapped-submit paths are **bit-identical** to the snapshot
-``TickEngine`` path — same padded batches, same jitted step, same drift
-bookkeeping sequence — on all three workload families and under both
-execution plans.  Plus: eager ServiceSpec/EngineConfig validation, the
-persistent query registry (add/update/drop with stable handles), two-in-
-flight TickHandle ordering, the compile_s/wall_s split, and the deprecation-
-shim equivalence (TickEngine.run ≡ a blocking KnnSession loop).
+The acceptance contract of the session API: the ``KnnSession`` delta-
+update and overlapped-submit paths are **bit-identical** to a second session
+fed whole snapshots through the blocking ``process_tick`` — same padded
+batches, same jitted step, same drift bookkeeping sequence — on all three
+workload families and under both execution plans.  Plus: eager ServiceSpec
+validation, the persistent query registry (add/update/drop with stable
+handles), two-in-flight TickHandle ordering, the compile_s/wall_s split, and
+``process_tick`` ≡ the handle loop.
 """
 import os
 import subprocess
 import sys
-import warnings
 
 import jax
 import numpy as np
 import pytest
 
 from repro.api import KnnSession, QueryHandle, ServiceSpec
-from repro.core import EngineConfig, TickEngine, knn_bruteforce_chunked
+from repro.core import knn_bruteforce_chunked
 from repro.data import make_workload
+from repro.testing import run_ticks
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 NDEV = jax.device_count()
@@ -32,13 +32,6 @@ def _spec(plan="single", **kw):
                 delta_pad=64)
     base.update(kw)
     return ServiceSpec(**base)
-
-
-def _engine(spec):
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        return TickEngine(spec.engine_config(), origin=spec.origin,
-                          side=spec.side)
 
 
 # ----------------------------------------------------------------- validation
@@ -58,32 +51,18 @@ def _engine(spec):
     (dict(precision="nope"), r"unknown precision 'nope'.*mixed"),
     (dict(merge="nope"), r"unknown merge backend 'nope'.*fused_multi"),
     (dict(collect="nope"), r"unknown collect mode 'nope'.*stats"),
+    (dict(k=0), r"k must be >= 1, got 0"),
+    (dict(window=0), r"window must be >= 1, got 0"),
+    (dict(origin=(0.0, 0.0, 0.0)), r"origin must be an \(x, y\) pair"),
+    (dict(mesh_shape=(2, 0)), r"query, object"),
+    (dict(maintenance="incremental", churn_budget=1.5),
+     r"churn_budget must be in \(0, 1\], got 1.5"),
 ])
 def test_service_spec_validates_eagerly(bad, match):
+    """Bad names and geometry raise at construction, listing what is valid,
+    instead of surfacing as a deep registry KeyError on the first tick."""
     with pytest.raises(ValueError, match=match):
         ServiceSpec(**bad)
-
-
-@pytest.mark.parametrize("bad, match", [
-    (dict(backend="nope"), r"unknown backend.*registered SCAN backends"),
-    (dict(plan="nope"), r"unknown execution plan.*registered plans"),
-    (dict(chunk=100, window=64), r"chunk.*multiple of window"),
-    (dict(k=3000, chunk=2048, window=256), r"k.*<= chunk"),
-])
-def test_engine_config_validates_eagerly(bad, match):
-    """Bad names used to surface only as a deep registry KeyError on first use."""
-    with pytest.raises(ValueError, match=match):
-        EngineConfig(**bad)
-
-
-def test_spec_subsumes_engine_config_roundtrip():
-    cfg = EngineConfig(k=8, th_quad=48, l_max=6, window=64, chunk=1024,
-                       backend="brute", plan="sharded", mesh_shape=1,
-                       precision="mixed", merge="fused_multi")
-    spec = ServiceSpec.from_engine(cfg, origin=(1.0, 2.0), side=9_000.0)
-    assert spec.engine_config() == cfg
-    assert spec.origin == (1.0, 2.0) and spec.side == 9_000.0
-    assert spec.precision == "mixed" and spec.merge == "fused_multi"
 
 
 # ------------------------------------------------- delta-update parity (tent)
@@ -102,7 +81,8 @@ def _moved_subset(rng, pts, frac, side=22_500.0):
 @pytest.mark.parametrize("dist", ["uniform", "gaussian", "network"])
 def test_delta_updates_bit_identical_to_snapshot(dist):
     """N scattered updates (applied in several chunks) ≡ the equivalent full
-    snapshot through the TickEngine path — ids AND distances bitwise."""
+    snapshot through a second session's process_tick — ids AND distances
+    bitwise."""
     w = make_workload(700, dist, seed=5)
     pts = w.positions().copy()
     qid = np.arange(len(pts), dtype=np.int32)
@@ -112,7 +92,7 @@ def test_delta_updates_bit_identical_to_snapshot(dist):
     sess = KnnSession(spec)
     sess.ingest_objects(pts)
     hq = sess.register_queries(pts, qid)
-    eng = _engine(spec)
+    snap = KnnSession(spec)
 
     cur = pts
     for t in range(3):
@@ -123,7 +103,7 @@ def test_delta_updates_bit_identical_to_snapshot(dist):
                 sess.update_objects(ids[part], cur[ids[part]])
             sess.update_queries(hq, cur)
         r_s = sess.submit().result()
-        r_e = eng.process_tick(cur, cur, qid)
+        r_e = snap.process_tick(cur, cur, qid)
         np.testing.assert_array_equal(r_s.nn_idx, r_e.nn_idx)
         np.testing.assert_array_equal(r_s.nn_dist, r_e.nn_dist)
         assert r_s.rebuilt == r_e.rebuilt
@@ -139,7 +119,7 @@ def test_delta_updates_bit_identical_sharded_plan():
     sess = KnnSession(spec)
     sess.ingest_objects(pts)
     hq = sess.register_queries(pts, qid)
-    eng = _engine(spec)
+    snap = KnnSession(spec)
     cur = pts
     for t in range(2):
         if t > 0:
@@ -147,7 +127,7 @@ def test_delta_updates_bit_identical_sharded_plan():
             sess.update_objects(ids, cur[ids])
             sess.update_queries(hq, cur)
         r_s = sess.submit().result()
-        r_e = eng.process_tick(cur, cur, qid)
+        r_e = snap.process_tick(cur, cur, qid)
         np.testing.assert_array_equal(r_s.nn_idx, r_e.nn_idx)
         np.testing.assert_array_equal(r_s.nn_dist, r_e.nn_dist)
 
@@ -163,7 +143,7 @@ def _object_plan_spec(plan):
 def test_delta_routing_single_shard_batch(plan):
     """Routing edge 1: an update batch whose every moved row is owned by ONE
     object shard — the grouped scatter must stay bit-identical to the
-    snapshot engine path (DESIGN.md §12 ownership rule)."""
+    snapshot path (DESIGN.md §12 ownership rule)."""
     w = make_workload(400, "gaussian", seed=11, hotspots=3)
     pts = w.positions().copy()
     qid = np.arange(len(pts), dtype=np.int32)
@@ -171,9 +151,9 @@ def test_delta_routing_single_shard_batch(plan):
     sess = KnnSession(spec)
     sess.ingest_objects(pts)
     hq = sess.register_queries(pts, qid)
-    eng = _engine(spec)
+    snap = KnnSession(spec)
     r_s = sess.submit().result()
-    r_e = eng.process_tick(pts, pts, qid)
+    r_e = snap.process_tick(pts, pts, qid)
     np.testing.assert_array_equal(r_s.nn_idx, r_e.nn_idx)
 
     owners = sess.object_shards(np.arange(len(pts)))
@@ -188,7 +168,7 @@ def test_delta_routing_single_shard_batch(plan):
     sess.update_objects(ids, cur[ids])
     sess.update_queries(hq, cur)
     r_s = sess.submit().result()
-    r_e = eng.process_tick(cur, cur, qid)
+    r_e = snap.process_tick(cur, cur, qid)
     np.testing.assert_array_equal(r_s.nn_idx, r_e.nn_idx)
     np.testing.assert_array_equal(r_s.nn_dist, r_e.nn_dist)
 
@@ -205,9 +185,9 @@ def test_delta_routing_row_crosses_shard_ownership(plan):
     sess = KnnSession(spec)
     sess.ingest_objects(pts)
     hq = sess.register_queries(pts, qid)
-    eng = _engine(spec)
+    snap = KnnSession(spec)
     sess.submit().result()
-    eng.process_tick(pts, pts, qid)
+    snap.process_tick(pts, pts, qid)
 
     # the Morton-first object, teleported to the far corner: rank 0 -> n-1
     mover = int(np.asarray(sess.index.ids)[0])
@@ -217,7 +197,7 @@ def test_delta_routing_row_crosses_shard_ownership(plan):
     sess.update_objects([mover], cur[mover][None])
     sess.update_queries(hq, cur)
     r_s = sess.submit().result()
-    r_e = eng.process_tick(cur, cur, qid)
+    r_e = snap.process_tick(cur, cur, qid)
     np.testing.assert_array_equal(r_s.nn_idx, r_e.nn_idx)
     np.testing.assert_array_equal(r_s.nn_dist, r_e.nn_dist)
     after = int(sess.object_shards([mover])[0])
@@ -229,7 +209,7 @@ def test_delta_routing_row_crosses_shard_ownership(plan):
 @pytest.mark.parametrize("plan", ["object_sharded", "hybrid"])
 def test_delta_routing_empty_delta_tick(plan):
     """Routing edge 3: an empty update batch is a no-op tick — identical
-    results to resubmitting unchanged state, and to the snapshot engine."""
+    results to resubmitting unchanged state, and to the snapshot path."""
     w = make_workload(250, "network", seed=19)
     pts = w.positions().copy()
     qid = np.arange(len(pts), dtype=np.int32)
@@ -237,12 +217,12 @@ def test_delta_routing_empty_delta_tick(plan):
     sess = KnnSession(spec)
     sess.ingest_objects(pts)
     sess.register_queries(pts, qid)
-    eng = _engine(spec)
+    snap = KnnSession(spec)
     r0 = sess.submit().result()
-    e0 = eng.process_tick(pts, pts, qid)
+    e0 = snap.process_tick(pts, pts, qid)
     sess.update_objects(np.zeros((0,), np.int32), np.zeros((0, 2), np.float32))
     r1 = sess.submit().result()
-    e1 = eng.process_tick(pts, pts, qid)
+    e1 = snap.process_tick(pts, pts, qid)
     np.testing.assert_array_equal(r0.nn_idx, r1.nn_idx)
     np.testing.assert_array_equal(r1.nn_idx, e1.nn_idx)
     np.testing.assert_array_equal(r1.nn_dist, e1.nn_dist)
@@ -305,8 +285,7 @@ def test_query_registry_add_drop_across_ticks(plan):
     assert isinstance(ha, QueryHandle) and ha.count == 90
 
     def reference(qpos, qid):
-        eng = _engine(spec)
-        return eng.process_tick(pts, qpos, qid)
+        return KnnSession(spec).process_tick(pts, qpos, qid)
 
     # tick 0: A + B
     r0 = sess.submit().result()
@@ -361,7 +340,8 @@ def test_update_queries_moves_only_that_group():
     qa2 = np.clip(qa + 50.0, 0, 22_499).astype(np.float32)
     sess.update_queries(ha, qa2)
     r = sess.submit().result()
-    ref = _engine(spec).process_tick(pts, np.concatenate([qa2, qb]), None)
+    ref = KnnSession(spec).process_tick(pts, np.concatenate([qa2, qb]),
+                                        None)
     np.testing.assert_array_equal(r.nn_idx, ref.nn_idx)
     np.testing.assert_array_equal(r.nn_dist, ref.nn_dist)
 
@@ -379,8 +359,8 @@ def test_two_in_flight_handles_any_collection_order():
         w.advance()
 
     spec = _spec()
-    eng = _engine(spec)
-    blocking = [eng.process_tick(p, p, qid) for p in frames]
+    snap = KnnSession(spec)
+    blocking = [snap.process_tick(p, p, qid) for p in frames]
 
     sess = KnnSession(spec)
     sess.ingest_objects(frames[0])
@@ -422,19 +402,18 @@ def test_result_of_finalized_tick_leaves_successor_pending():
     np.testing.assert_array_equal(ra.nn_idx, rb.nn_idx)  # static state
 
 
-# ------------------------------------------------------- shim equivalence
+# ------------------------------------ blocking snapshot call ≡ handle loop
 
 def test_tick_engine_shim_equivalent_to_session_loop():
-    """TickEngine.run ≡ the manual KnnSession loop, tick for tick, bitwise
-    (results, rebuilt flags, candidate counters)."""
-    cfg = EngineConfig(k=6, th_quad=16, l_max=5, window=32, chunk=64)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        eng = TickEngine(cfg)
+    """``KnnSession.process_tick`` (the blocking snapshot call, driven by
+    ``run_ticks``) ≡ the handle loop (register_queries / update_queries /
+    submit().result()), tick for tick, bitwise (results, rebuilt flags,
+    candidate and iteration counters)."""
+    spec = ServiceSpec(k=6, th_quad=16, l_max=5, window=32, chunk=64)
     w1 = make_workload(600, "gaussian", seed=2, hotspots=4)
-    engine_res = eng.run(w1, ticks=3)
+    snapshot_res = run_ticks(KnnSession(spec), w1, ticks=3)
 
-    sess = KnnSession(ServiceSpec.from_engine(cfg))
+    sess = KnnSession(spec)
     w2 = make_workload(600, "gaussian", seed=2, hotspots=4)
     hq = None
     session_res = []
@@ -448,17 +427,12 @@ def test_tick_engine_shim_equivalent_to_session_loop():
         session_res.append(sess.submit().result())
         w2.advance()
 
-    for re_, rs in zip(engine_res, session_res):
-        np.testing.assert_array_equal(re_.nn_idx, rs.nn_idx)
-        np.testing.assert_array_equal(re_.nn_dist, rs.nn_dist)
-        assert re_.rebuilt == rs.rebuilt
-        assert re_.candidates == rs.candidates
-        assert re_.iterations == rs.iterations
-
-
-def test_tick_engine_warns_deprecation():
-    with pytest.warns(DeprecationWarning, match="KnnSession"):
-        TickEngine(EngineConfig(k=4, th_quad=16, l_max=5, window=32, chunk=64))
+    for rp, rs in zip(snapshot_res, session_res):
+        np.testing.assert_array_equal(rp.nn_idx, rs.nn_idx)
+        np.testing.assert_array_equal(rp.nn_dist, rs.nn_dist)
+        assert rp.rebuilt == rs.rebuilt
+        assert rp.candidates == rs.candidates
+        assert rp.iterations == rs.iterations
 
 
 # ------------------------------------------------------- compile_s split
@@ -477,17 +451,14 @@ def test_compile_time_split_from_wall_time():
     assert r0.compile_s > 0.0
     assert r1.compile_s == 0.0
     assert r0.wall_s >= 0.0 and r1.wall_s >= 0.0
-    # the shim surfaces the same split; its tick 1 is the FIRST snapshot
-    # re-ingest of this shape, which runs the "rebuild" maintenance mode —
-    # a distinct static, hence its own one-time compile (DESIGN.md §15) —
-    # so steady state (compile_s == 0) starts at tick 2
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        eng = TickEngine(EngineConfig(k=5, th_quad=24, l_max=6, window=32,
-                                      chunk=96))
-    e0 = eng.process_tick(pts, pts[:33], None)
-    e1 = eng.process_tick(pts, pts[:33], None)
-    e2 = eng.process_tick(pts, pts[:33], None)
+    # the blocking snapshot call surfaces the same split; its tick 1 is the
+    # FIRST snapshot re-ingest of this shape, which runs the "rebuild"
+    # maintenance mode — a distinct static, hence its own one-time compile
+    # (DESIGN.md §15) — so steady state (compile_s == 0) starts at tick 2
+    snap = KnnSession(_spec(k=5, window=32, chunk=96))
+    e0 = snap.process_tick(pts, pts[:33], None)
+    e1 = snap.process_tick(pts, pts[:33], None)
+    e2 = snap.process_tick(pts, pts[:33], None)
     assert e0.compile_s >= 0.0 and e1.compile_s >= 0.0
     assert e2.compile_s == 0.0
 
@@ -712,7 +683,7 @@ def test_update_objects_duplicate_ids_last_wins():
     expect = pts.copy()
     expect[7], expect[12] = upd[3], upd[4]  # last observation per id
     r = sess.submit().result()
-    ref = _engine(spec)
+    ref = KnnSession(spec)
     ref.process_tick(pts, pts, np.arange(300, dtype=np.int32))
     ref_r = ref.process_tick(expect, pts, np.arange(300, dtype=np.int32))
     np.testing.assert_array_equal(r.nn_idx, ref_r.nn_idx)
@@ -904,11 +875,11 @@ def test_device_handles_stay_valid_across_submits_and_rebuild():
 
     spec = _spec(k=k, th_quad=32, l_max=6, window=64, chunk=512,
                  rebuild_factor=1.5)
-    eng = _engine(spec)
-    ref = [eng.process_tick(uniform, uniform, qid),
-           eng.process_tick(uniform, uniform, qid),
-           eng.process_tick(clustered, clustered, qid),
-           eng.process_tick(clustered, clustered, qid)]
+    snap = KnnSession(spec)
+    ref = [snap.process_tick(uniform, uniform, qid),
+           snap.process_tick(uniform, uniform, qid),
+           snap.process_tick(clustered, clustered, qid),
+           snap.process_tick(clustered, clustered, qid)]
 
     sess = KnnSession(spec)
     sess.ingest_objects(uniform)
@@ -990,16 +961,15 @@ def test_session_error_surface():
 
 def test_session_parity_on_forced_8_device_mesh():
     """The acceptance criterion on real multi-device XLA: delta ingest +
-    overlapped submit through KnnSession is bit-identical to the snapshot
-    TickEngine path under BOTH plans on an 8-device host mesh, all three
+    overlapped submit through KnnSession is bit-identical to a second
+    session's snapshot ticks under BOTH plans on an 8-device host mesh, all three
     workload families.  Subprocess: device count must precede jax init."""
     code = r"""
-import os, warnings
+import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax
 assert jax.device_count() == 8, jax.device_count()
 from repro.api import KnnSession, ServiceSpec
-from repro.core import EngineConfig, TickEngine
 from repro.data import make_workload
 
 for plan in ("single", "sharded"):
@@ -1012,10 +982,8 @@ for plan in ("single", "sharded"):
         for _ in range(3):
             frames.append(w.positions().copy()); w.advance()
         qid = np.arange(400, dtype=np.int32)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            eng = TickEngine(spec.engine_config())
-        ref = [eng.process_tick(p, p, qid) for p in frames]
+        snap = KnnSession(spec)
+        ref = [snap.process_tick(p, p, qid) for p in frames]
 
         sess = KnnSession(spec)
         sess.ingest_objects(frames[0])

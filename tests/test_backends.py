@@ -8,24 +8,24 @@ sets up to k-th-distance ties, on easy *and* adversarial inputs — skewed
 from both the pipeline and the brute-force jnp baseline.
 
 Also pins the serving-layer contract introduced by the device-resident tick
-refactor: ``TickEngine.process_tick`` never routes through the host-side
+refactor: ``KnnSession.process_tick`` never routes through the host-side
 chunk loop.
 """
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import KnnSession, ServiceSpec
 from repro.core import (
-    EngineConfig,
     KDTree,
     QueryExecutor,
-    TickEngine,
     available_backends,
     build_index,
     knn_query_batch,
     knn_query_batch_chunked,
 )
 from repro.data import make_workload
+from repro.testing import run_ticks
 
 BACKENDS = available_backends()
 
@@ -124,9 +124,9 @@ def test_engine_never_uses_host_chunk_loop(monkeypatch):
     monkeypatch.setattr(pipeline, "knn_query_batch_chunked", boom)
     monkeypatch.setattr(pipeline, "knn_query_batch", boom)
 
-    eng = TickEngine(EngineConfig(k=4, th_quad=16, l_max=5, window=32, chunk=256))
+    sess = KnnSession(ServiceSpec(k=4, th_quad=16, l_max=5, window=32, chunk=256))
     w = make_workload(600, "uniform", seed=1)
-    results = eng.run(w, ticks=2)
+    results = run_ticks(sess, w, ticks=2)
     assert len(results) == 2
     assert results[0].nn_dist.shape == (600, 4)
     assert np.isfinite(results[1].nn_dist).all()
@@ -134,14 +134,14 @@ def test_engine_never_uses_host_chunk_loop(monkeypatch):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_engine_backend_config_parity(backend):
-    """EngineConfig.backend threads through to identical tick results."""
+    """ServiceSpec.backend threads through to identical tick results."""
     w = make_workload(700, "gaussian", seed=2, hotspots=4)
     pts = w.positions()
     qpos, qid = w.query_batch()
-    eng = TickEngine(
-        EngineConfig(k=6, th_quad=16, l_max=5, window=32, chunk=256, backend=backend)
+    sess = KnnSession(
+        ServiceSpec(k=6, th_quad=16, l_max=5, window=32, chunk=256, backend=backend)
     )
-    res = eng.process_tick(pts, qpos, qid)
+    res = sess.process_tick(pts, qpos, qid)
     tree = KDTree(pts)
     _, rd = tree.query_batch(qpos, 6, qid=qid)
     np.testing.assert_allclose(res.nn_dist, rd, rtol=1e-5, atol=1e-3)

@@ -71,9 +71,9 @@ def test_cost_balanced_knob_validation():
 
 def test_plans_carry_partitioner():
     """resolve_plan threads the partitioner into every mesh plan; the
-    EngineConfig/ServiceSpec name knob rejects unknown partitioners."""
-    from repro.api import ServiceSpec
-    from repro.core import EngineConfig
+    ServiceSpec name knob rejects unknown partitioners and reaches the
+    session's plan."""
+    from repro.api import KnnSession, ServiceSpec
 
     for name in ("sharded", "object_sharded", "hybrid"):
         p = resolve_plan(name, num_devices=(1, 1) if name == "hybrid" else 1,
@@ -83,11 +83,10 @@ def test_plans_carry_partitioner():
         q = resolve_plan(name, num_devices=(1, 1) if name == "hybrid" else 1)
         assert q.partitioner == EqualPartitioner()
     with pytest.raises(ValueError, match="unknown partitioner"):
-        EngineConfig(partitioner="nope")
-    with pytest.raises(ValueError, match="unknown partitioner"):
         ServiceSpec(partitioner="nope")
-    assert ServiceSpec(partitioner="cost_balanced").engine_config().partitioner \
-        == "cost_balanced"
+    sess = KnnSession(ServiceSpec(plan="sharded", mesh_shape=1,
+                                  partitioner="cost_balanced"))
+    assert isinstance(sess.plan.partitioner, CostBalancedPartitioner)
 
 
 # -------------------------------------------------------------- boundaries

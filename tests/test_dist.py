@@ -1,4 +1,4 @@
-"""Logical-axis sharding rules + a reduced end-to-end dry-run on fake devices."""
+"""Logical-axis sharding rules + a cross-pod train step on fake devices."""
 import os
 import subprocess
 import sys
@@ -45,47 +45,6 @@ def test_constrain_noop_outside_mesh():
     x = jnp.ones((4, 4))
     y = constrain(x, ("batch", None))
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
-
-def test_reduced_dryrun_on_fake_devices():
-    """8 fake devices, 2x4 mesh, smoke config: lower+compile a sharded train
-    step + a decode step, assert collectives appear and memory is sane.
-
-    Runs in a subprocess because the device count must be set before jax init.
-    """
-    code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, jax.numpy as jnp, dataclasses
-from repro.configs import get_smoke_config
-from repro.dist import use_rules
-from repro.launch.specs import abstract_train_state, input_specs, abstract_decode_state, shard_struct
-from repro.configs.base import ShapeCell
-from repro.train import make_train_step, OptConfig
-from repro.models import decode_step
-from repro.launch.hlo_stats import collective_stats
-
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-cfg = dataclasses.replace(get_smoke_config("qwen3_moe_235b_a22b"), n_experts=8, top_k=2)
-shape = ShapeCell("t", 32, 8, "train")
-with use_rules(mesh):
-    params, opt = abstract_train_state(cfg)
-    batch = input_specs(cfg, shape)
-    comp = jax.jit(make_train_step(cfg, OptConfig())).lower(params, opt, batch).compile()
-    cs = collective_stats(comp.as_text())
-    assert cs["total_count"] > 0, "expected collectives in sharded train step"
-    dshape = ShapeCell("d", 64, 8, "decode")
-    state = abstract_decode_state(cfg, dshape)
-    tok = input_specs(cfg, dshape)["tokens"]
-    pos = shard_struct((), jnp.int32, ())
-    fn = lambda p, st, t, q: decode_step(p, cfg, st, t, q)
-    comp2 = jax.jit(fn).lower(params, state, tok, pos).compile()
-print("REDUCED_DRYRUN_OK")
-"""
-    env = dict(os.environ, PYTHONPATH=SRC)
-    r = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True)
-    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
-    assert "REDUCED_DRYRUN_OK" in r.stdout
 
 
 def test_crosspod_trainstep_on_fake_devices():

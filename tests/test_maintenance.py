@@ -19,7 +19,6 @@ The cross-plan lockstep property (incremental ≡ rebuild, every tick, across
 the plan × partitioner grid on however many devices exist) lives in
 tests/test_properties.py.
 """
-import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +26,6 @@ import pytest
 
 from repro.api import KnnSession, ServiceSpec
 from repro.core import (
-    EngineConfig,
     MAINTENANCE_MODES,
     build_index,
     knn_bruteforce,
@@ -675,16 +673,7 @@ def test_validation_rejects_bad_maintenance_knobs():
     with pytest.raises(ValueError, match="churn_budget"):
         ServiceSpec(maintenance="incremental", churn_budget=0.0)
     with pytest.raises(ValueError, match="churn_budget"):
-        EngineConfig(churn_budget=1.5)
+        ServiceSpec(churn_budget=1.5)
     with pytest.raises(ValueError, match="maintenance"):
-        EngineConfig(maintenance="never")
+        ServiceSpec(maintenance="never")
     assert "rebuild" in MAINTENANCE_MODES and "incremental" in MAINTENANCE_MODES
-
-
-def test_spec_round_trips_maintenance_knobs():
-    cfg = EngineConfig(maintenance="incremental", churn_budget=0.1)
-    spec = ServiceSpec.from_engine(cfg)
-    assert spec.maintenance == "incremental" and spec.churn_budget == 0.1
-    cfg2 = spec.engine_config()
-    assert cfg2.maintenance == "incremental" and cfg2.churn_budget == 0.1
-    assert dataclasses.asdict(cfg) == dataclasses.asdict(cfg2)

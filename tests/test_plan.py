@@ -18,13 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import KnnSession, ServiceSpec
 from repro.core import (
-    EngineConfig,
     HybridPlan,
     ObjectShardedPlan,
     ShardedPlan,
     SinglePlan,
-    TickEngine,
     available_plans,
     build_index,
     knn_bruteforce_chunked,
@@ -32,6 +31,7 @@ from repro.core import (
     resolve_plan,
 )
 from repro.data import make_workload
+from repro.testing import run_ticks
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 NDEV = jax.device_count()
@@ -249,14 +249,14 @@ def test_sharded_pad_strip_regression(nq):
 
 
 def test_engine_sharded_pad_strip_q1():
-    """The engine path: a single query through the sharded tick step."""
+    """The session path: a single query through the sharded tick step."""
     w = make_workload(400, "uniform", seed=9)
-    eng = TickEngine(
-        EngineConfig(k=4, th_quad=16, l_max=5, window=32, chunk=32,
+    sess = KnnSession(
+        ServiceSpec(k=4, th_quad=16, l_max=5, window=32, chunk=32,
                      plan="sharded", mesh_shape=NDEV)
     )
     qpos = w.positions()[:1]
-    res = eng.process_tick(w.positions(), qpos, np.array([0], np.int32))
+    res = sess.process_tick(w.positions(), qpos, np.array([0], np.int32))
     assert res.nn_idx.shape == (1, 4)
     bi, bd = knn_bruteforce_chunked(
         w.positions(), qpos, np.array([0], np.int32), k=4, chunk=32
@@ -264,17 +264,17 @@ def test_engine_sharded_pad_strip_q1():
     np.testing.assert_allclose(res.nn_dist, bd, rtol=1e-5, atol=1e-3)
 
 
-# ------------------------------------------------------ engine plan parity
+# ----------------------------------------------------- session plan parity
 
 def test_engine_plan_parity_over_ticks():
-    """TickEngine under plan=sharded == plan=single, tick for tick, bitwise."""
+    """A session under plan=sharded == plan=single, tick for tick, bitwise."""
     def run(plan):
-        eng = TickEngine(
-            EngineConfig(k=6, th_quad=16, l_max=5, window=32, chunk=64,
+        sess = KnnSession(
+            ServiceSpec(k=6, th_quad=16, l_max=5, window=32, chunk=64,
                          plan=plan, mesh_shape=NDEV if plan == "sharded" else None)
         )
         w = make_workload(600, "gaussian", seed=2, hotspots=4)
-        return eng.run(w, ticks=3)
+        return run_ticks(sess, w, ticks=3)
 
     single, sharded = run("single"), run("sharded")
     for rs, rh in zip(single, sharded):
@@ -288,17 +288,17 @@ def test_engine_plan_parity_over_ticks():
     ("hybrid", None),           # None -> balanced factorization
 ])
 def test_engine_object_plan_parity_over_ticks(plan, mesh):
-    """TickEngine under the object-axis plans == plan=single, tick for tick,
+    """A session under the object-axis plans == plan=single, tick for tick,
     bitwise on results.  (Stats — iterations/candidates — legitimately differ:
     local trees prune differently; the canonical-selection contract makes
     results partition-invariant anyway, see DESIGN.md §12.)"""
     def run(p, m):
-        eng = TickEngine(
-            EngineConfig(k=6, th_quad=16, l_max=5, window=32, chunk=64,
+        sess = KnnSession(
+            ServiceSpec(k=6, th_quad=16, l_max=5, window=32, chunk=64,
                          plan=p, mesh_shape=m)
         )
         w = make_workload(600, "gaussian", seed=2, hotspots=4)
-        return eng.run(w, ticks=3)
+        return run_ticks(sess, w, ticks=3)
 
     for rs, rh in zip(run("single", None), run(plan, mesh)):
         np.testing.assert_array_equal(rs.nn_idx, rh.nn_idx)
@@ -311,7 +311,7 @@ def test_sharded_determinism_on_forced_8_device_mesh():
     """The acceptance criterion on real multi-device XLA: an 8-device CPU grid
     (forced host devices) produces bit-identical results to the single plan on
     all three workload families for EVERY mesh plan — sharded (8-way query),
-    object_sharded (8-way object) and hybrid (the 2x4 grid) — engine path
+    object_sharded (8-way object) and hybrid (the 2x4 grid) — session path
     included.
 
     Runs in a subprocess because the device count must be set before jax init.
@@ -319,12 +319,12 @@ def test_sharded_determinism_on_forced_8_device_mesh():
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import warnings
-warnings.filterwarnings("ignore", category=DeprecationWarning)
 import numpy as np, jax, jax.numpy as jnp
 assert jax.device_count() == 8, jax.device_count()
-from repro.core import EngineConfig, TickEngine, build_index, knn_query_batch_chunked
+from repro.api import KnnSession, ServiceSpec
+from repro.core import build_index, knn_query_batch_chunked
 from repro.data import make_workload
+from repro.testing import run_ticks
 
 MESHES = [("sharded", 8), ("object_sharded", 8), ("hybrid", (2, 4))]
 for dist in ("uniform", "gaussian", "network"):
@@ -338,11 +338,11 @@ for dist in ("uniform", "gaussian", "network"):
         np.testing.assert_array_equal(a_d, b_d, err_msg=f"{dist}/{plan}")
 
 w = make_workload(400, "gaussian", seed=3, hotspots=3)
-ref = TickEngine(EngineConfig(k=4, th_quad=16, l_max=5, window=32, chunk=32)).run(
-    make_workload(400, "gaussian", seed=3, hotspots=3), ticks=2)
+ref = run_ticks(KnnSession(ServiceSpec(k=4, th_quad=16, l_max=5, window=32, chunk=32)),
+                make_workload(400, "gaussian", seed=3, hotspots=3), ticks=2)
 for plan, mesh in MESHES:
-    eng = TickEngine(EngineConfig(k=4, th_quad=16, l_max=5, window=32, chunk=32, plan=plan, mesh_shape=mesh))
-    res = eng.run(make_workload(400, "gaussian", seed=3, hotspots=3), ticks=2)
+    sess = KnnSession(ServiceSpec(k=4, th_quad=16, l_max=5, window=32, chunk=32, plan=plan, mesh_shape=mesh))
+    res = run_ticks(sess, make_workload(400, "gaussian", seed=3, hotspots=3), ticks=2)
     assert res[0].nn_dist.shape == (400, 4)
     for r, s in zip(ref, res):
         np.testing.assert_array_equal(r.nn_idx, s.nn_idx, err_msg=plan)
